@@ -92,7 +92,9 @@ print(json.dumps(out))
 def run(quick: bool = True):
     t0 = time.time()
     steps = 25 if quick else 60
-    env = dict(os.environ, PYTHONPATH="src")
+    # a CPU simulation by design: the child must not reach for the chip
+    # this process may hold
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, "-c", _SUB, str(steps)],
                        capture_output=True, text=True, env=env,
                        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),

@@ -22,6 +22,7 @@ import zlib
 
 from repro.core import CompressionSpec, container
 from repro.cluster import ParallelCompressor
+from repro.cluster._env import worker_env
 
 from .common import dataset, emit, save_json
 
@@ -38,7 +39,7 @@ def effective_cores(procs: int = 4) -> float:
     """Aggregate CPU throughput of ``procs`` concurrent workers vs. one —
     the hard ceiling on any process-parallel speedup on this host."""
     serial = _busy(0)
-    with multiprocessing.get_context("spawn").Pool(procs) as pool:
+    with worker_env(), multiprocessing.get_context("spawn").Pool(procs) as pool:
         pool.map(_busy, range(procs))  # exclude worker spawn from the window
         t0 = time.time()
         pool.map(_busy, range(procs))

@@ -19,6 +19,9 @@ def main(argv=None) -> None:
                     help="comma-separated bench names (e.g. methods,speed)")
     args = ap.parse_args(argv)
 
+    from repro.launch.jax_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (
         bench_backends,
         bench_blocksize,

@@ -22,6 +22,12 @@ Because rank cuts align with chunk boundaries and every registered scheme
 transforms blocks independently, the assembled file is **bit-identical to
 the serial writer** (:func:`repro.core.container.write_field`) for any rank
 count — rank-count invariance is a tested guarantee, not an accident.
+
+The rank workers run on the host CPU (``cluster._env.worker_env``): one
+process holds an accelerator.  A ``device="jax"`` spec therefore spans
+ranks only where the parent's backend is the CPU too; on an accelerator the
+workers' bytes would differ from the serial writer's, so such a call raises
+(:func:`check_rank_device`).
 """
 from __future__ import annotations
 
@@ -44,11 +50,7 @@ from repro.dist.offsets import exclusive_offsets_np
 
 from .decompose import chunk_spans
 
-__all__ = ["ParallelCompressor"]
-
-#: env override for the worker start method ("spawn" is jax-safe; "fork" is
-#: faster to boot but inherits the parent's initialized XLA runtime)
-_START_ENV = "REPRO_CLUSTER_START"
+__all__ = ["ParallelCompressor", "check_rank_device"]
 
 #: the paper's per-stage timing as live series (parent-side wall clock)
 _PHASE_SECONDS = obs.histogram(
@@ -76,6 +78,23 @@ def _rank_tracing(rank, trace_path):
     finally:
         trace.TRACER.disable()
         trace.TRACER.save(trace_path)
+
+
+def check_rank_device(spec: CompressionSpec, ranks: int) -> None:
+    """Raise ValueError when ``spec`` routes stage 1 to the kernels, more
+    than one rank would run it, and this process's JAX backend is not the
+    CPU the rank workers are pinned to."""
+    if spec.device != "jax" or ranks <= 1:
+        return
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise ValueError(
+            f"device='jax' with ranks={ranks}: rank workers run on the CPU, "
+            f"while this process's backend is {backend!r} (one process per "
+            "chip), so their bytes would differ from the serial writer's; "
+            "use ranks=1 on an accelerator")
 
 
 def _encode_rank(task) -> tuple[list[int], list[int], list[int], list]:
@@ -125,28 +144,26 @@ class ParallelCompressor:
         Worker-pool size and the default rank count per :meth:`compress`
         call (individual calls may use fewer ranks — the pool is shared, so
         one compressor amortizes worker startup across rank counts).
-    start_method:
-        ``multiprocessing`` start method.  Default ``"spawn"`` (fresh
-        interpreter per rank — safe with an initialized jax runtime in the
-        parent); override with ``"fork"`` or the ``REPRO_CLUSTER_START`` env
-        var when boot time matters more.
 
     The pool is created lazily on the first multi-rank compress and reused
     until :meth:`close`.  ``ranks=1`` calls stay in-process.
     """
 
-    def __init__(self, ranks: int, start_method: str | None = None):
+    def __init__(self, ranks: int):
         self.ranks = int(ranks)
         if self.ranks < 1:
             raise ValueError(f"ranks must be >= 1, got {ranks}")
-        self._start = (start_method or os.environ.get(_START_ENV) or "spawn")
+        # "spawn": each rank is a fresh interpreter that picks up the CPU
+        # pin of worker_env; a forked rank would inherit the parent's
+        # initialized JAX runtime, and with it the parent's chip
+        self._start = "spawn"
         self._pool = None
 
     def _get_pool(self):
         if self._pool is None:
             from ._env import worker_env
             ctx = multiprocessing.get_context(self._start)
-            with worker_env():  # children inherit the thread caps at exec
+            with worker_env():  # children inherit caps and CPU pin at exec
                 self._pool = ctx.Pool(self.ranks)
         return self._pool
 
@@ -178,7 +195,10 @@ class ParallelCompressor:
         """Write ``field`` to ``path`` as a CZ2 container; returns bytes
         written.  Output is bit-identical to
         ``container.write_compressed(path, field, spec, extra_header)``
-        for every rank count and every registered scheme.
+        for every rank count and every registered scheme.  Raises
+        ValueError for a ``device="jax"`` spec spread over several ranks
+        from a process whose backend is not the CPU
+        (:func:`check_rank_device`).
         """
         spec = spec.validate()
         nranks = self._nranks(ranks)
@@ -193,6 +213,7 @@ class ParallelCompressor:
             return container.write_stream(
                 path, pipe.iter_chunks(data, records=records), header,
                 fsync=fsync, records=records)
+        check_rank_device(spec, nranks)
         _COMPRESSIONS.inc(ranks=nranks)
 
         # when the parent is tracing, every worker task also gets a trace

@@ -14,9 +14,10 @@ recorded in container headers for provenance but is not required to decode.
 A file written with ``device="jax"`` decodes bit-exact on host for schemes
 whose kernels are integer-exact (zfpx, lorenzo) and within the scheme's
 declared error bound otherwise (wavelet — fp rounding only).  When the
-Pallas toolchain is unavailable, ``device="jax"`` falls back to host with a
-:class:`DeviceFallbackWarning` instead of failing, so containers stay
-readable everywhere.
+installed JAX has no Pallas at all, ``device="jax"`` falls back to host
+with a :class:`DeviceFallbackWarning` and counts
+``cz_kernel_fallbacks_total``; any other failure to import the kernels
+raises.
 """
 from __future__ import annotations
 
@@ -53,15 +54,19 @@ def check_device(device: str) -> None:
 
 
 def kernel_ops():
-    """``repro.kernels.ops`` if the Pallas toolchain imports, else ``None``
-    (resolved once and cached — the fallback decision is per-process)."""
+    """``repro.kernels.ops``, or ``None`` when the installed JAX lacks the
+    Pallas toolchain (resolved once and cached — the fallback decision is
+    per-process).  A kernel module that fails to import for any other
+    reason raises: a broken kernel must not pass for a missing one."""
     global _OPS
     if _OPS is _UNSET:
         try:
             from repro.kernels import ops as _ops
-            _OPS = _ops
-        except Exception:  # missing/broken pallas: gate, don't crash
-            _OPS = None
+        except ModuleNotFoundError as e:
+            if not (e.name or "").startswith("jax"):
+                raise
+            _ops = None
+        _OPS = _ops
     return _OPS
 
 
@@ -69,7 +74,7 @@ def resolve_ops(spec):
     """Kernel-ops module when ``spec`` routes stage 1 to a device, else None.
 
     ``None`` means "use the host path" — either because the spec asked for
-    it or because the kernels are unavailable (warned, not raised: decode of
+    it or because this JAX has no Pallas (warned, not raised: decode of
     device-written containers must succeed on any host).
     """
     check_device(spec.device)
@@ -81,8 +86,8 @@ def resolve_ops(spec):
         _events.event("device.fallback", level="warn", requested="jax",
                       used="host")
         warnings.warn(
-            "device='jax' requested but repro.kernels.ops is unavailable "
-            "(no Pallas toolchain); stage 1 falling back to the host path",
+            "device='jax' requested but this JAX has no Pallas toolchain; "
+            "stage 1 falling back to the host path",
             DeviceFallbackWarning, stacklevel=3)
     return ops
 

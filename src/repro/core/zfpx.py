@@ -31,6 +31,8 @@ import jax.numpy as jnp
 __all__ = [
     "SCALE_BITS",
     "sequency_perm",
+    "cell_emax",
+    "pow2",
     "encode",
     "decode",
     "fwd_lift_cell",
@@ -49,6 +51,28 @@ def sequency_perm() -> np.ndarray:
     i, j, k = idx // 16, (idx // 4) % 4, idx % 4
     order = np.lexsort((k, j, i, i + j + k))
     return order.astype(np.int32)
+
+
+def pow2(k):
+    """Exact ``2.0 ** k`` in float32 for an int32 array ``k``.
+
+    Built from exponent bits as the product of two normal powers of two, so
+    it is exact wherever the result is representable, on every backend (a
+    backend's ``exp2`` need not be) and inside a Pallas kernel alike."""
+    k = jnp.asarray(k, jnp.int32)
+    h = k >> 1
+
+    def bits(e):
+        return jax.lax.bitcast_convert_type((e + 127) << 23, jnp.float32)
+
+    return bits(h) * bits(k - h)
+
+
+def cell_emax(amax):
+    """Per-cell block exponent: ``e`` with ``amax = m * 2^e``, ``m`` in
+    [0.5, 1) (``frexp``), or ``_ZERO_EMAX`` for an all-zero cell."""
+    _, e = jnp.frexp(amax)
+    return jnp.where(amax > 0, e, _ZERO_EMAX).astype(jnp.int32)
 
 
 def _lift4(x, y, z, w):
@@ -118,10 +142,8 @@ def _drop_bits(emax, eps: float):
 def encode(blocks, eps: float = 1e-3):
     """blocks (B, n, n, n) float32 -> (emax (B, nc) int32, q (B, nc, 64) int32)."""
     cells = _to_cells(jnp.asarray(blocks, jnp.float32))     # (B, nc, 4,4,4)
-    amax = jnp.max(jnp.abs(cells), axis=(-3, -2, -1))       # (B, nc)
-    _, e = jnp.frexp(amax)                                   # amax = m * 2^e, m in [0.5,1)
-    emax = jnp.where(amax > 0, e, _ZERO_EMAX).astype(jnp.int32)
-    scale = jnp.exp2((SCALE_BITS - emax).astype(jnp.float32))
+    emax = cell_emax(jnp.max(jnp.abs(cells), axis=(-3, -2, -1)))  # (B, nc)
+    scale = pow2(SCALE_BITS - emax)
     q = jnp.round(cells * scale[..., None, None, None]).astype(jnp.int32)
     q = fwd_lift_cell(q)
     q = q.reshape(*q.shape[:-3], 64)[..., jnp.asarray(sequency_perm())]
@@ -136,7 +158,7 @@ def decode(emax, q, eps: float = 1e-3, n: int = 32):
     inv = jnp.argsort(jnp.asarray(sequency_perm()))
     cells = q[..., inv].reshape(*q.shape[:-1], 4, 4, 4)
     cells = inv_lift_cell(cells)
-    scale = jnp.exp2((emax - SCALE_BITS).astype(jnp.float32))
+    scale = pow2(emax - SCALE_BITS)
     out = cells.astype(jnp.float32) * scale[..., None, None, None]
     out = jnp.where((emax == _ZERO_EMAX)[..., None, None, None], 0.0, out)
     return _from_cells(out, n)

@@ -31,7 +31,6 @@ def exclusive_offsets_sharded(sizes, mesh, axis_name: str):
     Each shard computes its local exclusive cumsum and adds the total of all
     preceding shards (one all-gather of per-shard totals — O(devices) bytes).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def _exscan(local):
@@ -40,6 +39,6 @@ def exclusive_offsets_sharded(sizes, mesh, axis_name: str):
         base = jnp.sum(jnp.where(jnp.arange(totals.shape[0]) < idx, totals, 0))
         return jnp.cumsum(local) - local + base
 
-    fn = shard_map(_exscan, mesh=mesh,
-                   in_specs=P(axis_name), out_specs=P(axis_name))
+    fn = jax.shard_map(_exscan, mesh=mesh,
+                       in_specs=P(axis_name), out_specs=P(axis_name))
     return fn(jnp.asarray(sizes))

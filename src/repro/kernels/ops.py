@@ -1,8 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels, instrumented per call.
 
-``interpret=None`` auto-selects: real Pallas lowering on TPU, interpret mode
-elsewhere (this container is CPU-only; interpret mode executes the kernel
-body faithfully for correctness validation).
+``interpret=None`` auto-selects from the default backend: real Pallas
+lowering on ``tpu``, interpret mode on ``cpu`` (where the tests run; it
+executes the kernel body faithfully for correctness validation), and an
+error on any other backend — a process that meant to use a TPU and came up
+elsewhere must not run the kernels interpreted without saying so.
 
 Every wrapper is wrapped in device-tier observability: first call per
 argument signature (shapes/dtypes + static values — the same key ``jax.jit``
@@ -136,7 +138,12 @@ def _instrument(name: str):
 def _interp(interpret: bool | None) -> bool:
     if interpret is not None:
         return interpret
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels lower for 'tpu' and run interpreted on 'cpu'; "
+            f"the default backend is {backend!r}")
+    return backend == "cpu"
 
 
 @_instrument("wavelet_forward")

@@ -42,6 +42,7 @@ import numpy as np
 
 from repro.core import DEVICES, SCHEMES, CompressionSpec, compression_ratio, psnr
 from repro.core import container
+from repro.launch.jax_cache import enable_compile_cache
 
 
 @contextlib.contextmanager
@@ -290,6 +291,7 @@ def gc_main(argv) -> int:
 def parallel_main(argv) -> int:
     """Rank-parallel single-shared-file compression (repro.cluster.engine)."""
     from repro.cluster import ParallelCompressor
+    from repro.cluster.engine import check_rank_device
     from repro.fields import CloudConfig, cavitation_fields
 
     ap = argparse.ArgumentParser(prog="cz-compress parallel")
@@ -331,6 +333,10 @@ def parallel_main(argv) -> int:
         zero_bits=args.zero_bits, stage2=args.stage2,
         precision=args.precision, device=args.device,
         buffer_bytes=args.buffer_bytes, extra=_tune_extra(ap, args)))
+    try:
+        check_rank_device(spec, args.ranks)
+    except ValueError as e:
+        ap.error(str(e))
     if args.source == "npy":
         fields = {"field": np.load(args.npy).astype(np.float32)}
     else:
@@ -526,6 +532,7 @@ def stats_main(argv) -> int:
 
 
 def main(argv=None):
+    enable_compile_cache()
     argv = sys.argv[1:] if argv is None else list(argv)
     if argv and argv[0] == "inspect":
         raise SystemExit(inspect_main(argv[1:]))

@@ -11,15 +11,9 @@ __all__ = ["make_mesh", "make_production_mesh", "batch_axes", "model_axis"]
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where supported.
-
-    ``jax.sharding.AxisType`` only exists from jax 0.5; on 0.4.x meshes are
-    implicitly Auto, so the kwarg is simply omitted.
-    """
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis of type Auto."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -28,6 +28,7 @@ from repro.core import CompressionSpec
 from repro.ckpt import Checkpointer
 from repro.data.tokens import DataConfig, batch_at
 from repro.dist.fault import PreemptionHandler, StragglerWatchdog
+from repro.launch.jax_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import ModelSettings
 from repro.train.optim import OptConfig
@@ -56,6 +57,7 @@ def main(argv=None):
     ap.add_argument("--data-branching", type=int, default=8)
     ap.add_argument("--data-regimes", type=int, default=4)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = ARCHS[args.arch]
     if args.reduced:

@@ -11,6 +11,13 @@ if _SRC not in sys.path:
 
 
 def pytest_configure(config):
+    # the tests leave JAX's persistent compilation cache off, also where an
+    # entry point they call places it (repro.launch.jax_cache) — in this
+    # process and in the entry-point processes they start
+    import jax
+
+    os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+    jax.config.update("jax_enable_compilation_cache", False)
     config.addinivalue_line(
         "markers",
         "device: exercises the Pallas kernel (device='jax') paths — slower "
