@@ -1,0 +1,7 @@
+"""Share of its roofline that ``lorenzo_encode`` reached: least time from
+``cellbench/roofline.py`` over the kernel's device time in the trace."""
+from cellbench import roofline
+
+
+def read(obs):
+    return roofline.share(obs, "lorenzo_encode")
