@@ -168,12 +168,17 @@ def write_stream(path: str, chunk_iter: Iterable[tuple[bytes, int]],
         f.write(MAGIC)
         f.write(_FOOTER_PTR.pack(0))  # patched once the footer offset is known
         for chunk, nblk in chunk_iter:
-            f.write(chunk)
+            with trace.span("store.write", bytes=len(chunk)):
+                f.write(chunk)
             sizes.append(len(chunk))
             nblks.append(nblk)
             crcs.append(zlib.crc32(chunk) & 0xFFFFFFFF)
-        return commit_footer(f, base_header, sizes, nblks, crcs, f.tell(),
-                             fsync=fsync, records=records)
+        footer_off = f.tell()
+        with trace.span("store.write") as sp:
+            total = commit_footer(f, base_header, sizes, nblks, crcs,
+                                  footer_off, fsync=fsync, records=records)
+            sp.set(bytes=total - footer_off, fsync=fsync)
+        return total
 
 
 def build_field_header(pipe: Pipeline, source,
@@ -190,8 +195,9 @@ def build_field_header(pipe: Pipeline, source,
     header = pipe.base_header()
     if data.ndim == 3:
         header["field_shape"] = list(data.shape)
-        data = np.asarray(
-            blk.blockify(np.asarray(data, spec.np_dtype), spec.block_size))
+        with trace.span("blockify", bytes=int(data.nbytes)):
+            data = np.asarray(
+                blk.blockify(np.asarray(data, spec.np_dtype), spec.block_size))
     elif data.ndim != 4:
         raise ValueError(f"expected 3D field or 4D block batch, got {data.shape}")
     header["raw_bytes"] = int(data.size * spec.np_dtype.itemsize)
@@ -604,8 +610,12 @@ class FieldReader:
         """One chunk plus whether this call actually inflated it (``False``
         = LRU hit).  The flag is decided under the reader lock, so accounting
         built on it (e.g. the serve scheduler's bytes-decoded counter) stays
-        exact under concurrency."""
+        exact under concurrency.  The wait for the lock is recorded as
+        ``reader.wait``."""
+        t_wait = time.perf_counter_ns()
         with self._lock:
+            trace.record("reader.wait", t_wait, time.perf_counter_ns(),
+                         chunk=ci)
             if self._closed:
                 raise ValueError(
                     f"FieldReader for {self.path!r} is closed "
@@ -618,19 +628,20 @@ class FieldReader:
             self.cache_misses += 1
             _READS.inc(result="miss")
             off = int(self._chunk_off[ci])
-            t0 = time.perf_counter_ns()
-            buf = (self._prefetcher.take(ci)
-                   if self._prefetcher is not None else None)
-            if buf is None:
-                buf = self.store.get(
-                    self.key, (off, off + self.header["chunk_sizes"][ci]))
+            with trace.span("fetch", chunk=ci) as sp:
+                t0 = time.perf_counter_ns()
+                buf = (self._prefetcher.take(ci)
+                       if self._prefetcher is not None else None)
+                if buf is None:
+                    buf = self.store.get(
+                        self.key, (off, off + self.header["chunk_sizes"][ci]))
+                sp.set(bytes=len(buf))
             t1 = time.perf_counter_ns()
             out = self._pipe.decompress_chunk(buf, self._chunk_nblk[ci], self.format)
             t2 = time.perf_counter_ns()
             _FETCHED.inc(len(buf))
             _FETCH_SECONDS.observe((t1 - t0) / 1e9)
             _DECODE_SECONDS.observe((t2 - t1) / 1e9)
-            trace.record("fetch", t0, t1, chunk=ci, bytes=len(buf))
             self._cache[ci] = out
             while len(self._cache) > self._cache_chunks:
                 self._cache.popitem(last=False)

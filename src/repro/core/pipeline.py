@@ -84,8 +84,7 @@ _DEC_SECONDS = obs.histogram("cz_pipeline_decode_seconds",
                              labelnames=("scheme",))
 
 
-def _account_encode(scheme: str, ci: int, raw: int, enc: int,
-                    t0_ns: int, t1_ns: int) -> None:
+def _account_encode(scheme: str, raw: int, enc: int, seconds: float) -> None:
     _ENC_CHUNKS.inc(scheme=scheme)
     _RAW_BYTES.inc(raw, scheme=scheme)
     _ENC_BYTES.inc(enc, scheme=scheme)
@@ -93,10 +92,7 @@ def _account_encode(scheme: str, ci: int, raw: int, enc: int,
     total_enc = _ENC_BYTES.value(scheme=scheme)
     if total_enc:
         _RATIO.set(total_raw / total_enc, scheme=scheme)
-    _ENC_SECONDS.observe((t1_ns - t0_ns) / 1e9, scheme=scheme)
-    trace.record("encode", t0_ns, t1_ns, chunk=ci, scheme=scheme,
-                 raw_bytes=raw, encoded_bytes=enc,
-                 ratio=round(raw / enc, 3) if enc else None)
+    _ENC_SECONDS.observe(seconds, scheme=scheme)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -237,12 +233,17 @@ class Pipeline:
         block_bytes = spec.np_dtype.itemsize * spec.block_size ** 3
 
         def encode(ci: int, lo: int, hi: int) -> tuple[bytes, dict | None]:
-            t0 = time.perf_counter_ns()
-            payload = self.scheme.serialize(s1, lo, hi, spec)
-            chunk = lossless.encode(payload, spec.stage2)
-            rec = self.scheme.chunk_record(s1, lo, hi, spec)
-            _account_encode(spec.scheme, ci, (hi - lo) * block_bytes,
-                            len(chunk), t0, time.perf_counter_ns())
+            raw = (hi - lo) * block_bytes
+            with trace.span("encode", chunk=ci, scheme=spec.scheme) as sp:
+                t0 = time.perf_counter_ns()
+                payload = self.scheme.serialize(s1, lo, hi, spec)
+                chunk = lossless.encode(payload, spec.stage2)
+                rec = self.scheme.chunk_record(s1, lo, hi, spec)
+                t1 = time.perf_counter_ns()
+                enc = len(chunk)
+                sp.set(raw_bytes=raw, encoded_bytes=enc,
+                       ratio=round(raw / enc, 3) if enc else None)
+            _account_encode(spec.scheme, raw, enc, (t1 - t0) / 1e9)
             return chunk, rec
 
         def emit(chunk: bytes, rec: dict | None, nblk: int):
@@ -323,18 +324,19 @@ class Pipeline:
 
     def decompress_chunk(self, buf: bytes, nblk: int,
                          fmt: int = CODEC_FORMAT) -> np.ndarray:
-        t0 = time.perf_counter_ns()
         spec = self.scheme.decode_spec(self.spec, fmt)
-        payload = lossless.decode(buf, spec.stage2)
-        blocks = self.scheme.deserialize(payload, nblk, spec)
-        # lossy schemes compute in float32; the dtype tag restores the field
-        # dtype (raw already deserializes in the tagged dtype — no-op there)
-        out = blocks.astype(spec.np_dtype, copy=False)
-        t1 = time.perf_counter_ns()
+        with trace.span("decode", scheme=spec.scheme, nblocks=nblk,
+                        encoded_bytes=len(buf)):
+            t0 = time.perf_counter_ns()
+            payload = lossless.decode(buf, spec.stage2)
+            blocks = self.scheme.deserialize(payload, nblk, spec)
+            # lossy schemes compute in float32; the dtype tag restores the
+            # field dtype (raw already deserializes in the tagged dtype —
+            # no-op there)
+            out = blocks.astype(spec.np_dtype, copy=False)
+            t1 = time.perf_counter_ns()
         _DEC_CHUNKS.inc(scheme=spec.scheme)
         _DEC_SECONDS.observe((t1 - t0) / 1e9, scheme=spec.scheme)
-        trace.record("decode", t0, t1, scheme=spec.scheme, nblocks=nblk,
-                     encoded_bytes=len(buf))
         return out
 
     def decompress_blocks(self, comp: CompressedField) -> np.ndarray:

@@ -32,6 +32,8 @@ from ._device import (  # noqa: F401  (re-export)
     resolve_ops,
     resolved_device,
     route,
+    to_device,
+    to_host,
 )
 
 if TYPE_CHECKING:  # avoid a runtime cycle with repro.core.pipeline
@@ -40,7 +42,7 @@ if TYPE_CHECKING:  # avoid a runtime cycle with repro.core.pipeline
 __all__ = ["Scheme", "SCHEMES", "register_scheme", "unregister_scheme",
            "get_scheme", "shuffle_bytes", "unshuffle_bytes",
            "DEVICES", "DeviceFallbackWarning", "check_device", "resolve_ops",
-           "resolved_device", "route"]
+           "resolved_device", "route", "to_device", "to_host"]
 
 _REGISTRY: dict[str, "Scheme"] = {}
 

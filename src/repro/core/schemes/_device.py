@@ -18,16 +18,24 @@ installed JAX has no Pallas at all, ``device="jax"`` falls back to host
 with a :class:`DeviceFallbackWarning` and counts
 ``cz_kernel_fallbacks_total``; any other failure to import the kernels
 raises.
+
+:func:`to_device` and :func:`to_host` are the copies between host and
+device memory that stage 1 and ``CZDataset.append`` make, each in a
+``copy.to_device`` / ``copy.to_host`` span that carries the bytes it moved.
 """
 from __future__ import annotations
 
+import sys
 import warnings
+
+import numpy as np
 
 from repro import obs
 from repro.obs import events as _events
+from repro.obs import trace
 
 __all__ = ["DEVICES", "DeviceFallbackWarning", "check_device", "kernel_ops",
-           "resolve_ops", "route", "resolved_device"]
+           "resolve_ops", "route", "resolved_device", "to_device", "to_host"]
 
 _FALLBACKS = obs.counter(
     "cz_kernel_fallbacks_total",
@@ -111,3 +119,34 @@ def resolved_device(spec, device_capable: bool) -> str:
     if spec.device == "jax" and device_capable and kernel_ops() is not None:
         return "jax"
     return "host"
+
+
+def _on_device(x) -> bool:
+    jax = sys.modules.get("jax")
+    return jax is not None and isinstance(x, jax.Array)
+
+
+def to_device(x, dtype):
+    """``jnp.asarray(x, dtype)``, in a ``copy.to_device`` span where ``x``
+    is a host array."""
+    import jax.numpy as jnp
+
+    if _on_device(x):
+        return jnp.asarray(x, dtype)
+    x = np.asarray(x)
+    with trace.span("copy.to_device", bytes=int(x.nbytes)):
+        return jnp.asarray(x, dtype)
+
+
+def to_host(*arrays) -> list[np.ndarray]:
+    """``np.asarray`` of each array, in one ``copy.to_host`` span whose
+    ``bytes`` counts the device arrays among them.  Host arrays pass through
+    uncounted, and with no device array there is no span: a field that is
+    already on the host is not copied."""
+    if not any(_on_device(a) for a in arrays):
+        return [np.asarray(a) for a in arrays]
+    with trace.span("copy.to_host") as sp:
+        out = [np.asarray(a) for a in arrays]
+        sp.set(bytes=sum(o.nbytes for a, o in zip(arrays, out)
+                         if _on_device(a)))
+    return out

@@ -20,7 +20,8 @@ import numpy as np
 import jax.numpy as jnp
 
 from .. import szx as _szx
-from . import Scheme, register_scheme, route, shuffle_bytes, unshuffle_bytes
+from . import (Scheme, register_scheme, route, shuffle_bytes, to_device,
+               to_host, unshuffle_bytes)
 
 
 @register_scheme
@@ -40,10 +41,11 @@ class LorenzoScheme(Scheme):
         return spec.eps
 
     def stage1(self, blocks_np, spec):
-        x = jnp.asarray(blocks_np, jnp.float32)
+        x = to_device(blocks_np, jnp.float32)
         _szx.check_eps(float(jnp.max(jnp.abs(x))), spec.eps)
         res = route(spec, _szx.encode, "lorenzo_encode")(x, eps=spec.eps)
-        return {"res": np.asarray(res)}
+        res, = to_host(res)
+        return {"res": res}
 
     def serialize(self, s1, lo, hi, spec) -> bytes:
         r = s1["res"][lo:hi].astype(np.int32, copy=False)

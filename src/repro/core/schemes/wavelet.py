@@ -18,7 +18,8 @@ import jax.numpy as jnp
 
 from .. import shuffle as shuf
 from .. import threshold, wavelets
-from . import Scheme, register_scheme, route, shuffle_bytes, unshuffle_bytes
+from . import (Scheme, register_scheme, route, shuffle_bytes, to_device,
+               to_host, unshuffle_bytes)
 
 
 @register_scheme
@@ -45,17 +46,14 @@ class WaveletScheme(Scheme):
         return self.BOUND_FACTOR * spec.eps
 
     def stage1(self, blocks_np, spec):
-        x = jnp.asarray(blocks_np, jnp.float32)
+        x = to_device(blocks_np, jnp.float32)
         n = spec.block_size
         fwd = route(spec, wavelets.forward3d, "wavelet_forward")
         coeffs = fwd(x, kind=spec.wavelet, levels=spec.levels)
         mask = threshold.significant_mask(coeffs, spec.eps, spec.levels)
         c = wavelets.coarse_side(n, spec.levels)
-        return {
-            "mask": np.asarray(mask),
-            "coeffs": np.asarray(coeffs),
-            "coarse": np.asarray(coeffs[..., :c, :c, :c]),
-        }
+        mask, coeffs, coarse = to_host(mask, coeffs, coeffs[..., :c, :c, :c])
+        return {"mask": mask, "coeffs": coeffs, "coarse": coarse}
 
     def serialize(self, s1, lo, hi, spec) -> bytes:
         mask = s1["mask"][lo:hi]

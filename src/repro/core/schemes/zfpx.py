@@ -14,7 +14,8 @@ import numpy as np
 import jax.numpy as jnp
 
 from .. import zfpx as _zfp
-from . import Scheme, register_scheme, route, shuffle_bytes, unshuffle_bytes
+from . import (Scheme, register_scheme, route, shuffle_bytes, to_device,
+               to_host, unshuffle_bytes)
 
 
 @register_scheme
@@ -38,9 +39,10 @@ class ZfpxScheme(Scheme):
         return self.BOUND_FACTOR * spec.eps
 
     def stage1(self, blocks_np, spec):
-        x = jnp.asarray(blocks_np, jnp.float32)
+        x = to_device(blocks_np, jnp.float32)
         emax, q = route(spec, _zfp.encode, "zfpx_encode")(x, eps=spec.eps)
-        return {"emax": np.asarray(emax), "q": np.asarray(q)}
+        emax, q = to_host(emax, q)
+        return {"emax": emax, "q": q}
 
     def serialize(self, s1, lo, hi, spec) -> bytes:
         emax = np.clip(s1["emax"][lo:hi], -127, 127).astype(np.int8)

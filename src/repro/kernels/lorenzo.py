@@ -66,7 +66,7 @@ def _dec_kernel(r_ref, o_ref, *, eps: float, n: int):
     o_ref[...] = r.astype(jnp.float32) * (2.0 * eps)
 
 
-def _call(x, kern, out_dtype, eps, tile_blocks, interpret):
+def _call(x, kern, name, out_dtype, eps, tile_blocks, interpret):
     b, n = x.shape[0], x.shape[-1]
     tb = min(tile_blocks, b)
     if b % tb:
@@ -79,17 +79,18 @@ def _call(x, kern, out_dtype, eps, tile_blocks, interpret):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((b, n, n * n), out_dtype),
         interpret=interpret,
+        name=name,
     )(x.reshape(b, n, n * n))
     return out.reshape(x.shape)
 
 
 def lorenzo_encode_pallas(blocks, eps: float = 1e-3,
                           tile_blocks: int = DEFAULT_TILE_BLOCKS, interpret: bool = True):
-    return _call(jnp.asarray(blocks, jnp.float32), _enc_kernel, jnp.int32,
-                 eps, tile_blocks, interpret)
+    return _call(jnp.asarray(blocks, jnp.float32), _enc_kernel,
+                 "lorenzo_encode", jnp.int32, eps, tile_blocks, interpret)
 
 
 def lorenzo_decode_pallas(residuals, eps: float = 1e-3,
                           tile_blocks: int = DEFAULT_TILE_BLOCKS, interpret: bool = True):
-    return _call(jnp.asarray(residuals, jnp.int32), _dec_kernel, jnp.float32,
-                 eps, tile_blocks, interpret)
+    return _call(jnp.asarray(residuals, jnp.int32), _dec_kernel,
+                 "lorenzo_decode", jnp.float32, eps, tile_blocks, interpret)

@@ -6,35 +6,22 @@ executes the kernel body faithfully for correctness validation), and an
 error on any other backend — a process that meant to use a TPU and came up
 elsewhere must not run the kernels interpreted without saying so.
 
-Every wrapper is wrapped in device-tier observability: first call per
-argument signature (shapes/dtypes + static values — the same key ``jax.jit``
-compiles on) is a **compile**, later calls are steady-state **execute**, and
-the two phases get separate span names (``kernel.compile`` /
-``kernel.execute``) and separate ``cz_kernel_seconds`` series — a
-compilation stall and a slow steady-state kernel are different problems and
-must not share a histogram.
-
-Timing is synchronized (``jax.block_until_ready``) only when someone is
-looking: on first-call compiles (jit compilation is host-synchronous
-anyway), while the process tracer is enabled, or inside a collecting
-request context (the serve tier's tail sampling) — then async dispatch
-can't flatter the numbers.  Otherwise the wrapper records dispatch time
-only and returns the unforced value, preserving JAX's async-dispatch
-pipelining on accelerator backends.  ``CZ_KERNEL_SYNC=1``/``0`` in the
-environment (or assigning :data:`SYNC`) forces the choice either way.
+Every wrapper counts its calls (``cz_kernel_calls_total``) and its first
+call per argument signature (shapes/dtypes + static values — the same key
+``jax.jit`` compiles on) as a compile (``cz_kernel_compiles_total``).  The
+wrappers time nothing and never block: they return the unforced array, so
+JAX's async dispatch pipelines on accelerator backends whether or not
+anyone is tracing.  A kernel's device time is the profiler trace's (the
+``XLA Modules`` line names each ``jit_<kernel>`` program).
 """
 from __future__ import annotations
 
 import functools
-import os
 import threading
-import time
 
 import jax
 
 from repro import obs
-from repro.obs import context as _context
-from repro.obs import trace
 
 from .lorenzo import lorenzo_decode_pallas, lorenzo_encode_pallas
 from .wavelet3d import wavelet3d_forward, wavelet3d_inverse
@@ -56,21 +43,6 @@ _COMPILES = obs.counter(
 _CALLS = obs.counter(
     "cz_kernel_calls_total", "Kernel wrapper calls.",
     labelnames=("kernel", "device"))
-_SECONDS = obs.histogram(
-    "cz_kernel_seconds",
-    "Kernel wall time split by compile/execute phase (block_until_ready "
-    "on compiles and while tracing/tail collection is active; async "
-    "dispatch time otherwise).",
-    buckets=obs.FAST_BUCKETS, labelnames=("kernel", "device", "phase"))
-
-#: tri-state host-device sync override for kernel timing: ``True`` forces
-#: ``block_until_ready`` on every call, ``False`` never blocks, ``None``
-#: (default) blocks only when the timing is observable — first-call
-#: compile, process tracer enabled, or a collecting request context.
-#: Seeded from ``CZ_KERNEL_SYNC`` when set.
-SYNC: bool | None = (None if "CZ_KERNEL_SYNC" not in os.environ
-                     else os.environ["CZ_KERNEL_SYNC"].lower()
-                     not in ("0", "false", ""))
 
 
 def _sig(x):
@@ -82,15 +54,13 @@ def _sig(x):
 
 
 def _instrument(name: str):
-    """Wrap one jitted kernel with compile/execute phase detection, spans,
-    and the ``cz_kernel_*`` metrics.
+    """Wrap one jitted kernel with the ``cz_kernel_*`` counters.
 
-    Phase detection mirrors ``jax.jit``'s cache key (argument
-    shapes/dtypes + static values) with a per-wrapper seen-set: the first
-    call for a signature is ``compile``, the rest ``execute``.  An
-    approximation — jit cache eviction can recompile a "seen" signature —
-    but right for the question the metrics answer: how much wall time is
-    warm-up vs steady state.
+    The first call for a signature counts as a compile, through a
+    per-wrapper seen-set that mirrors ``jax.jit``'s cache key (argument
+    shapes/dtypes + static values).  An approximation — jit cache eviction
+    can recompile a "seen" signature — but right for the question the
+    counter answers: how many calls paid for warm-up.
     """
 
     def deco(fn):
@@ -106,28 +76,10 @@ def _instrument(name: str):
                 if first:
                     seen.add(key)
             device = jax.default_backend()
-            phase = "compile" if first else "execute"
-            sync = SYNC
-            if sync is None:
-                # block only when the timing is observable: compiles are
-                # host-synchronous anyway, and an active tracer/collecting
-                # request context needs honest span durations; steady-state
-                # uninstrumented calls keep async dispatch pipelining
-                ctx = _context.current()
-                sync = (first or trace.tracing()
-                        or (ctx is not None and ctx.collecting))
-            t0 = time.perf_counter_ns()
             out = fn(*a, **k)
-            if sync:
-                out = jax.block_until_ready(out)
-            t1 = time.perf_counter_ns()
             if first:
                 _COMPILES.inc(kernel=name, device=device)
             _CALLS.inc(kernel=name, device=device)
-            _SECONDS.observe((t1 - t0) / 1e9, kernel=name, device=device,
-                             phase=phase)
-            trace.record(f"kernel.{phase}", t0, t1, kernel=name,
-                         device=device)
             return out
 
         return wrapper

@@ -125,6 +125,7 @@ def _call(blocks, kind: str, levels: int | None, inverse: bool,
         out_specs=pl.BlockSpec((tb, n, n, n), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(blocks.shape, jnp.float32),
         interpret=interpret,
+        name="wavelet_inverse" if inverse else "wavelet_forward",
     )(jnp.asarray(blocks, jnp.float32), *[jnp.asarray(M) for M in mats])
 
 

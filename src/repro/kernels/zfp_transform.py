@@ -116,6 +116,7 @@ def zfpx_encode_pallas(blocks, eps: float = 1e-3,
             jax.ShapeDtypeStruct((64, bp, nc), jnp.int32),
         ],
         interpret=interpret,
+        name="zfpx_encode",
     )(x)
     return emax[:b], jnp.transpose(q[:, :b], (1, 2, 0))
 
@@ -137,5 +138,6 @@ def zfpx_decode_pallas(emax, q, eps: float = 1e-3, n: int = 32,
         out_specs=pl.BlockSpec((4, 4, 4, tb, nc), lambda i: (0, 0, 0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((4, 4, 4, bp, nc), jnp.float32),
         interpret=interpret,
+        name="zfpx_decode",
     )(e, qs)
     return _from_slabs(out[:, :, :, :b], n)

@@ -13,6 +13,12 @@ shared no-op context manager after one attribute check, so instrumented hot
 paths (per-chunk encode, store gets) stay within noise when nobody is
 tracing (the ``bench_speed`` overhead budget is < 2%).
 
+While the tracer is enabled each span also opens a
+``jax.profiler.TraceAnnotation`` of its name, marked with the stat
+:data:`PROFILER_MARK`, so a running JAX profiler puts every span on the
+``/host:CPU`` plane of the same ``.xplane.pb`` as the device's operations
+(jax is taken from ``sys.modules``, never imported here).
+
 Clocks are monotonic (``time.perf_counter_ns``); each tracer also anchors a
 wall-clock epoch at :meth:`Tracer.enable` so traces from *different
 processes* can be merged onto one timeline: the cluster engine's worker
@@ -26,13 +32,18 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 
 from repro.obs import context as _context
 
-__all__ = ["Tracer", "TRACER", "span", "traced", "tracing", "enable",
-           "disable", "record", "reset", "save", "merge_traces"]
+__all__ = ["Tracer", "TRACER", "PROFILER_MARK", "span", "traced", "tracing",
+           "enable", "disable", "record", "reset", "save", "merge_traces"]
+
+#: stat set on each span's profiler annotation: what tells the program's
+#: spans apart from the runtime's own host events in a profiler trace
+PROFILER_MARK = "repro_span"
 
 
 class _NullSpan:
@@ -46,25 +57,46 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args):
+        pass
+
 
 _NULL = _NullSpan()
 
 
+def _annotation(name: str):
+    """An entered profiler annotation for ``name``, or None when jax is not
+    loaded."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    ann = jax.profiler.TraceAnnotation(name, **{PROFILER_MARK: 1})
+    ann.__enter__()
+    return ann
+
+
 class _Span:
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
         self._name = name
         self._args = args
 
+    def set(self, **args):
+        """Add args known only once the work is done (byte counts)."""
+        self._args.update(args)
+
     def __enter__(self):
+        self._ann = _annotation(self._name) if self._tracer.enabled else None
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        _emit(self._tracer, self._name, self._t0, time.perf_counter_ns(),
-              self._args)
+        t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        _emit(self._tracer, self._name, self._t0, t1, self._args)
         return False
 
 
@@ -146,8 +178,7 @@ class Tracer:
 
     def record(self, name: str, t0_ns: int, t1_ns: int, **args) -> None:
         """Append one complete event from explicit ``perf_counter_ns``
-        stamps — for instrumentation that already timed the work (the
-        pipeline's per-chunk path computes bytes/ratio after the fact)."""
+        stamps — for instrumentation that already timed the work."""
         if not self.enabled:
             return
         ev = {"name": name, "ph": "X", "cat": "repro",
@@ -256,8 +287,9 @@ def span(name: str, **args):
 
 def record(name: str, t0_ns: int, t1_ns: int, **args) -> None:
     """Record one already-timed span against the process tracer *and* the
-    active request context (instrumentation that computes byte counts after
-    the fact uses this instead of :func:`span`)."""
+    active request context: for work timed by other means (a lock wait).
+    It reaches no profiler trace; args known only at the end of a span go
+    through ``span.set`` instead."""
     if TRACER.enabled or _context.current() is not None:
         _emit(TRACER, name, t0_ns, t1_ns, args)
 

@@ -96,12 +96,9 @@ class SingleFlight:
                     trace.record("serve.flight", t0, time.perf_counter_ns(),
                                  key=str(key), followers=followers)
             return flight.future.result()
-        t0 = time.perf_counter_ns()
-        try:
+        with trace.span("serve.flight.wait", key=str(key),
+                        leader=flight.leader_rid):
             return flight.future.result()
-        finally:
-            trace.record("serve.flight.wait", t0, time.perf_counter_ns(),
-                         key=str(key), leader=flight.leader_rid)
 
 
 class ChunkScheduler:

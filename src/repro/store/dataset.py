@@ -28,6 +28,8 @@ import numpy as np
 from repro.core import container, metrics
 from repro.core.container import FieldReader
 from repro.core.pipeline import CompressionSpec
+from repro.core.schemes import to_host
+from repro.obs import trace
 
 from .backends import Store, open_store
 from .manifest import (
@@ -212,7 +214,7 @@ class CZDataset:
             for q, field in fields.items():
                 if not _QUANTITY_RE.match(q):
                     raise ValueError(f"invalid quantity name {q!r}")
-                field = np.asarray(field)
+                field, = to_host(field)
                 ent = self._m["quantities"].get(q)
                 if ent is not None and tuple(ent["shape"]) != field.shape:
                     raise ValueError(
@@ -255,7 +257,8 @@ class CZDataset:
                 ent["timesteps"].append(rec)
             self._m["next_t"] = t + 1
             self._m["version"] = int(self._m["version"]) + 1
-            write_manifest(self.store, self._m)
+            with trace.span("store.commit", t=t):
+                write_manifest(self.store, self._m)
             return t
 
     # -- random access -----------------------------------------------------

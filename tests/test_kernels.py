@@ -184,10 +184,9 @@ def test_wavelet_parity_property(b, n, kind, levels, seed):
 
 
 def test_kernel_metrics_split_compile_from_execute():
-    """The device-tier instrumentation distinguishes the first call per
-    signature (jit compile) from steady-state execution: compiles_total
-    advances once per new signature, calls_total per call, and
-    cz_kernel_seconds grows separate compile/execute series."""
+    """The device-tier counters distinguish the first call per signature
+    (jit compile) from steady-state execution: compiles_total advances once
+    per new signature, calls_total per call."""
     from repro import obs
 
     dev = __import__("jax").default_backend()
@@ -195,7 +194,7 @@ def test_kernel_metrics_split_compile_from_execute():
     lbl = {"kernel": kernel, "device": dev}
     compiles = obs.REGISTRY.get("cz_kernel_compiles_total")
     calls = obs.REGISTRY.get("cz_kernel_calls_total")
-    seconds = obs.REGISTRY.get("cz_kernel_seconds")
+    assert "cz_kernel_seconds" not in {m.name for m in obs.REGISTRY}
 
     x = blocks(2, 8, seed=991)  # fresh shape: unseen by earlier tests
     c0, n0 = compiles.value(**lbl), calls.value(**lbl)
@@ -209,51 +208,33 @@ def test_kernel_metrics_split_compile_from_execute():
     # a new eps is a new static value -> new jit cache entry -> compile
     ops.lorenzo_encode(x, eps=3e-3, interpret=True)
     assert compiles.value(**lbl) == c0 + 2
-
-    comp = seconds.snapshot(**lbl, phase="compile")
-    execd = seconds.snapshot(**lbl, phase="execute")
-    assert comp["count"] >= 2 and execd["count"] >= 2
+    assert calls.value(**lbl) == n0 + 4
 
 
-def test_kernel_sync_gated_on_observability(monkeypatch):
-    """block_until_ready runs only when the timing is observable — first-
-    call compiles, an enabled tracer, or a collecting request context —
-    so steady-state uninstrumented calls keep async dispatch (the
-    production path on accelerator backends).  SYNC forces either way."""
+def test_kernel_wrapper_returns_unforced_while_tracing(monkeypatch):
+    """With the process tracer on, a wrapper call neither blocks nor
+    records a span of its own: the traced run dispatches as the untraced
+    one does, and kernel time comes from the profiler's device trace."""
     import jax
 
     from repro import obs
-    from repro.obs import context as obs_context
 
-    assert not obs.TRACER.enabled
-    syncs = {"n": 0}
-    real = jax.block_until_ready
-
-    def counting(x):
-        syncs["n"] += 1
-        return real(x)
-
-    monkeypatch.setattr(jax, "block_until_ready", counting)
+    def forbidden(x):
+        raise AssertionError("kernel wrapper forced its result")
 
     x = blocks(3, 8, seed=771)
-    ops.lorenzo_encode(x, eps=5e-3, interpret=True)  # fresh sig: compile
-    assert syncs["n"] == 1
-    ops.lorenzo_encode(x, eps=5e-3, interpret=True)  # unobserved execute
-    assert syncs["n"] == 1
-    with obs_context.request(collect=True):  # tail collection active
-        ops.lorenzo_encode(x, eps=5e-3, interpret=True)
-    assert syncs["n"] == 2
+    monkeypatch.setattr(jax, "block_until_ready", forbidden)
+    obs.trace.reset()
     obs.trace.enable()
     try:
-        ops.lorenzo_encode(x, eps=5e-3, interpret=True)  # tracer active
+        out = ops.lorenzo_encode(x, eps=5e-3, interpret=True)
+        out2 = ops.lorenzo_encode(x, eps=5e-3, interpret=True)
     finally:
         obs.trace.disable()
-        obs.trace.reset()
-    assert syncs["n"] == 3
-    monkeypatch.setattr(ops, "SYNC", False)  # hard off wins over collection
-    with obs_context.request(collect=True):
-        ops.lorenzo_encode(x, eps=5e-3, interpret=True)
-    assert syncs["n"] == 3
-    monkeypatch.setattr(ops, "SYNC", True)  # hard on syncs unobserved calls
-    ops.lorenzo_encode(x, eps=5e-3, interpret=True)
-    assert syncs["n"] == 4
+    events = obs.TRACER.events()
+    obs.trace.reset()
+    monkeypatch.undo()
+    assert isinstance(out, jax.Array) and isinstance(out2, jax.Array)
+    assert not [e for e in events if e["name"].startswith("kernel.")]
+    ref = ops.lorenzo_encode(x, eps=5e-3, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out2), np.asarray(ref))
