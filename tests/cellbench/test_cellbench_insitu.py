@@ -3,12 +3,16 @@ traced run, the control, and the faults a compression cell can have, each
 of which must come out as not correct."""
 from __future__ import annotations
 
+import itertools
+
 import numpy as np
 import pytest
 
 from cellbench_testlib import run_small
 
-from cellbench import check, registry
+from cellbench import check, harness, registry
+
+INSITU = ["insitu_wavelet", "insitu_zfpx", "insitu_lorenzo"]
 
 
 def _assert_well_formed(result, workload, trace):
@@ -34,6 +38,53 @@ def test_rehearsal(workload, tmp_path):
     assert r["correct"], r["checks"]
     assert r["attempted"] >= 3 * 2 and r["failed"] == 0
     assert 1 < r["metrics"]["compress_ratio"]["value"] < 1000
+
+
+@pytest.mark.parametrize("workload", INSITU)
+def test_ratio_does_not_follow_the_window(workload, tmp_path):
+    """``compress_ratio`` is that of the first cycle of dumps: a window that
+    closes at once and one of several seconds read the same ratio."""
+    short = run_small(workload, seconds=0.0, workdir=tmp_path / "short")
+    long = run_small(workload, seconds=3.0, workdir=tmp_path / "long")
+    assert short["correct"] and long["correct"], (short, long)
+    assert long["attempted"] > short["attempted"]
+    assert (short["metrics"]["compress_ratio"]["value"]
+            == long["metrics"]["compress_ratio"]["value"])
+
+
+def _polls_until(n: int):
+    """``Context.expired`` that answers yes at its ``n``-th poll."""
+    polls = itertools.count(1)
+    return lambda self: next(polls) >= n
+
+
+def test_cycle_repeats_its_dumps(monkeypatch, tmp_path):
+    """Dump K of the window starts again from the initial state: its members
+    hold the same chunk bytes as dump 0's, and dump K+1's those of dump 1."""
+    from repro.core import container
+
+    monkeypatch.setattr(harness.Context, "expired", _polls_until(3))
+    r = run_small("insitu_zfpx", mix={"cycle_dumps": 2}, workdir=tmp_path)
+    assert r["correct"], r["checks"]
+    assert r["attempted"] == 4 * 3
+
+    def chunks(q, t):
+        path = tmp_path / "run" / q / f"t{t:06d}.cz"
+        return list(container.iter_compressed(str(path)))
+
+    for q in registry.config("cavitation_insitu_256")["qois"]:
+        assert chunks(q, 2) == chunks(q, 0)
+        assert chunks(q, 3) == chunks(q, 1)
+        assert chunks(q, 1) != chunks(q, 0)
+
+
+def test_window_commits_a_whole_cycle(tmp_path):
+    """A deadline that passes before K dumps still commits K, and no more."""
+    r = run_small("insitu_lorenzo", seconds=0.0, mix={"cycle_dumps": 3},
+                  workdir=tmp_path)
+    assert r["correct"], r["checks"]
+    assert r["attempted"] == 3 * 3
+    assert r["checks"]["mismatches"]["value"] == 0
 
 
 def test_traced_rehearsal(tmp_path):
@@ -115,4 +166,16 @@ def test_fault_is_not_correct(fault, monkeypatch, tmp_path):
     # a window of a few seconds makes several dumps even on a loaded host
     r = run_small("insitu_wavelet", seconds=3.0, workdir=tmp_path)
     assert r["attempted"] >= 3 * 2
+    assert not r["correct"], r["checks"]
+
+
+def test_stale_dumps_fail_when_the_last_repeats_dump_0(monkeypatch, tmp_path):
+    """Three dumps of a cycle of two: the last is dump 0 again, so a store
+    that commits dump 0's fields every time is caught by the first cycle's
+    last dump, which is always compared."""
+    _stale_append(monkeypatch)
+    monkeypatch.setattr(harness.Context, "expired", _polls_until(2))
+    r = run_small("insitu_wavelet", mix={"cycle_dumps": 2, "sampled_dumps": 0},
+                  workdir=tmp_path)
+    assert r["attempted"] == 3 * 3
     assert not r["correct"], r["checks"]
