@@ -14,10 +14,10 @@ spec = CompressionSpec(scheme="wavelet", wavelet="w3ai", eps=1e-3,
                        block_size=32, shuffle="byte")
 
 # one append = one committed timestep of all quantities; chunk encoding for
-# every member runs on a shared 4-thread pool (the paper's per-thread
-# writers), drained in order so the files match a serial write byte-for-byte
-with CZDataset("artifacts/example_dataset", mode="a", spec=spec,
-               workers=4) as ds:
+# every member runs on a shared pool, one thread per CPU (the paper's
+# per-thread writers), drained in order so the files match a serial write
+# byte-for-byte
+with CZDataset("artifacts/example_dataset", mode="a", spec=spec) as ds:
     t = ds.append(fields, time=9.4)
     for q in ds.quantities:
         ts = ds.timestep_info(q, t)

@@ -22,7 +22,7 @@ dt = cfl_dt(U)
 spec = CompressionSpec(scheme="wavelet", eps=1e-2, block_size=16)
 
 sim_t = io_t = 0.0
-ds = CZDataset("artifacts/insitu_dataset", mode="a", spec=spec, workers=4)
+ds = CZDataset("artifacts/insitu_dataset", mode="a", spec=spec)
 for snap in range(5):
     t0 = time.time()
     U = run(U, 10, dt=dt)

@@ -18,8 +18,7 @@ from repro.store import CZDataset
 # -- 1. compressed-field region serving over HTTP ----------------------------
 root = os.path.join(tempfile.mkdtemp(), "ds")
 with CZDataset(root, "a", spec=CompressionSpec(scheme="wavelet", eps=1e-3,
-                                               block_size=16),
-               workers=4) as ds:
+                                               block_size=16)) as ds:
     fields = cavitation_fields(CloudConfig(n=64), t=9.4)
     t = ds.append({"p": fields["p"], "rho": fields["rho"]}, time=9.4)
 

@@ -28,7 +28,7 @@ def _spdp_decode(buf: bytes) -> bytes:
 
 
 METHODS = {
-    "none": (lambda b: b, lambda b: b),
+    "none": (bytes, lambda b: b),
     "zlib": (lambda b: zlib.compress(b, 6), zlib.decompress),
     "zlib1": (lambda b: zlib.compress(b, 1), zlib.decompress),
     "zlib9": (lambda b: zlib.compress(b, 9), zlib.decompress),
@@ -45,7 +45,8 @@ METHODS = {
 }
 
 
-def encode(buf: bytes, method: str = "zlib") -> bytes:
+def encode(buf, method: str = "zlib") -> bytes:
+    """Stage-2 code of a payload (any bytes-like buffer) as ``bytes``."""
     return METHODS[method][0](buf)
 
 

@@ -214,7 +214,10 @@ class Pipeline:
         With ``workers > 1`` (or an external ``executor``, e.g. the store's
         :class:`~repro.store.ShardWriter` pool) chunk encoding is submitted to
         the pool a bounded window ahead while results are yielded strictly in
-        order — the output byte stream is identical to the serial path.
+        order — the output byte stream is identical to the serial path.  The
+        pool uses at most one thread per chunk, and a batch of one chunk is
+        encoded serially.  One ``stage2`` span (``workers``, ``chunks``)
+        times the whole drain, the consumer's handling of each chunk included.
 
         ``records`` (a caller-owned list) collects each chunk's
         :meth:`Scheme.chunk_record` in yield order — ``None`` entries for
@@ -251,33 +254,40 @@ class Pipeline:
                 records.append(rec)
             return chunk, nblk
 
-        nworkers = self.workers if workers is None else max(1, int(workers))
-        if executor is None and nworkers <= 1:
-            for ci, lo, hi in ranges:
-                yield emit(*encode(ci, lo, hi), hi - lo)
-            return
-
-        own_pool = executor is None
-        pool = executor or concurrent.futures.ThreadPoolExecutor(nworkers)
-        try:
-            # keep at most ~2x workers chunks in flight: parallelism without
-            # materializing the whole compressed chunk list
-            window = 2 * nworkers
-            it = iter(ranges)
-            pending: collections.deque = collections.deque(
-                (r, pool.submit(encode, *r)) for r in itertools.islice(it, window))
-            while pending:
-                (_ci, lo, hi), fut = pending.popleft()
-                nxt = next(it, None)
-                if nxt is not None:
-                    pending.append((nxt, pool.submit(encode, *nxt)))
-                chunk, rec = fut.result()
-                # the single ordered drain appends records in chunk order,
-                # so threaded collection matches the serial path exactly
-                yield emit(chunk, rec, hi - lo)
-        finally:
-            if own_pool:
-                pool.shutdown(wait=True, cancel_futures=True)
+        # no more threads than chunks; a lone chunk is encoded where it
+        # is consumed
+        nworkers = min(self.workers if workers is None else max(1, int(workers)),
+                       len(ranges))
+        serial = len(ranges) <= 1 or (executor is None and nworkers <= 1)
+        # the drain's wall time, the consumer's work on each chunk included
+        with trace.span("stage2", workers=1 if serial else nworkers,
+                        chunks=len(ranges)):
+            if serial:
+                for ci, lo, hi in ranges:
+                    yield emit(*encode(ci, lo, hi), hi - lo)
+                return
+            own_pool = executor is None
+            pool = executor or concurrent.futures.ThreadPoolExecutor(nworkers)
+            try:
+                # keep at most ~2x workers chunks in flight: parallelism
+                # without materializing the whole compressed chunk list
+                window = 2 * nworkers
+                it = iter(ranges)
+                pending: collections.deque = collections.deque(
+                    (r, pool.submit(encode, *r))
+                    for r in itertools.islice(it, window))
+                while pending:
+                    (_ci, lo, hi), fut = pending.popleft()
+                    nxt = next(it, None)
+                    if nxt is not None:
+                        pending.append((nxt, pool.submit(encode, *nxt)))
+                    chunk, rec = fut.result()
+                    # the single ordered drain appends records in chunk
+                    # order, so threaded collection matches the serial path
+                    yield emit(chunk, rec, hi - lo)
+            finally:
+                if own_pool:
+                    pool.shutdown(wait=True, cancel_futures=True)
 
     def compress_blocks(self, blocks_np: np.ndarray,
                         extra_header: dict | None = None) -> CompressedField:

@@ -40,7 +40,7 @@ if TYPE_CHECKING:  # avoid a runtime cycle with repro.core.pipeline
     from ..pipeline import CompressionSpec
 
 __all__ = ["Scheme", "SCHEMES", "register_scheme", "unregister_scheme",
-           "get_scheme", "shuffle_bytes", "unshuffle_bytes",
+           "get_scheme", "pack_payload", "shuffle_bytes", "unshuffle_bytes",
            "DEVICES", "DeviceFallbackWarning", "check_device", "resolve_ops",
            "resolved_device", "route", "to_device", "to_host"]
 
@@ -53,6 +53,29 @@ def shuffle_bytes(buf: bytes, mode: str, itemsize: int) -> bytes:
         return buf
     fn = _shuf.byte_shuffle if mode == "byte" else _shuf.bit_shuffle
     return fn(buf, itemsize)
+
+
+def pack_payload(head, stream, mode: str, itemsize: int) -> bytearray:
+    """One chunk's payload, written once into one buffer: the bytes of each
+    array in ``head`` in order, then ``stream``'s bytes shuffled by ``mode``.
+
+    The same bytes as concatenating ``tobytes()`` of each part and
+    :func:`shuffle_bytes` of the stream, without those whole-chunk copies;
+    the result goes to stage 2 as it is (any buffer will do there).
+    """
+    parts = [_shuf.byte_view(h) for h in head]
+    body = _shuf.byte_view(stream)
+    out = bytearray(sum(p.size for p in parts) + body.size)
+    view = np.frombuffer(out, np.uint8)
+    off = 0
+    for p in parts:
+        view[off:off + p.size] = p
+        off += p.size
+    if mode == "byte" and itemsize > 1:
+        _shuf.byte_shuffle(body, itemsize, out=view[off:])
+    else:
+        view[off:] = _shuf.byte_view(shuffle_bytes(body, mode, itemsize))
+    return out
 
 
 def unshuffle_bytes(buf: bytes, mode: str, itemsize: int) -> bytes:
@@ -127,7 +150,8 @@ class Scheme(abc.ABC):
 
     @abc.abstractmethod
     def serialize(self, s1: dict, lo: int, hi: int, spec: "CompressionSpec") -> bytes:
-        """Byte layout of blocks [lo, hi) from the stage-1 streams."""
+        """Byte layout of blocks [lo, hi) from the stage-1 streams (bytes,
+        or a ``bytearray`` such as :func:`pack_payload` writes)."""
 
     @abc.abstractmethod
     def deserialize(self, payload: bytes, nblk: int, spec: "CompressionSpec") -> np.ndarray:

@@ -20,7 +20,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from .. import szx as _szx
-from . import (Scheme, register_scheme, route, shuffle_bytes, to_device,
+from . import (Scheme, pack_payload, register_scheme, route, to_device,
                to_host, unshuffle_bytes)
 
 
@@ -49,7 +49,7 @@ class LorenzoScheme(Scheme):
 
     def serialize(self, s1, lo, hi, spec) -> bytes:
         r = s1["res"][lo:hi].astype(np.int32, copy=False)
-        return shuffle_bytes(r.tobytes(), spec.shuffle, 4)
+        return pack_payload([], r, spec.shuffle, 4)
 
     def deserialize(self, payload, nblk, spec):
         n = spec.block_size

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from .. import shuffle as shuf
 from .. import threshold, wavelets
-from . import (Scheme, register_scheme, route, shuffle_bytes, to_device,
+from . import (Scheme, pack_payload, register_scheme, route, to_device,
                to_host, unshuffle_bytes)
 
 
@@ -59,16 +59,13 @@ class WaveletScheme(Scheme):
         mask = s1["mask"][lo:hi]
         coeffs = s1["coeffs"][lo:hi]
         coarse = s1["coarse"][lo:hi].astype(np.float32)
-        details = coeffs[mask].astype(np.float32)
+        details = coeffs[mask].astype(np.float32, copy=False)
         if spec.zero_bits:
             details = shuf.zero_low_bits_np(details, spec.zero_bits)
         counts = mask.reshape(mask.shape[0], -1).sum(-1).astype(np.uint32)
         values = np.concatenate([coarse.reshape(-1), details])
-        return (
-            counts.tobytes()
-            + np.packbits(mask.reshape(-1)).tobytes()
-            + shuffle_bytes(values.tobytes(), spec.shuffle, 4)
-        )
+        return pack_payload([counts, np.packbits(mask.reshape(-1))], values,
+                            spec.shuffle, 4)
 
     def deserialize(self, payload, nblk, spec):
         n = spec.block_size

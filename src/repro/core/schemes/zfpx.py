@@ -14,7 +14,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from .. import zfpx as _zfp
-from . import (Scheme, register_scheme, route, shuffle_bytes, to_device,
+from . import (Scheme, pack_payload, register_scheme, route, to_device,
                to_host, unshuffle_bytes)
 
 
@@ -46,8 +46,8 @@ class ZfpxScheme(Scheme):
 
     def serialize(self, s1, lo, hi, spec) -> bytes:
         emax = np.clip(s1["emax"][lo:hi], -127, 127).astype(np.int8)
-        q = s1["q"][lo:hi].astype(np.int32)
-        return emax.tobytes() + shuffle_bytes(q.tobytes(), spec.shuffle, 4)
+        q = s1["q"][lo:hi].astype(np.int32, copy=False)
+        return pack_payload([emax], q, spec.shuffle, 4)
 
     def deserialize(self, payload, nblk, spec):
         n = spec.block_size

@@ -13,6 +13,7 @@ import numpy as np
 import jax.numpy as jnp
 
 __all__ = [
+    "byte_view",
     "byte_shuffle",
     "byte_unshuffle",
     "bit_shuffle",
@@ -22,11 +23,33 @@ __all__ = [
 ]
 
 
-def byte_shuffle(buf: bytes | np.ndarray, itemsize: int) -> bytes:
-    a = np.frombuffer(buf, dtype=np.uint8) if isinstance(buf, (bytes, bytearray)) else np.asarray(buf, np.uint8)
+def byte_view(buf) -> np.ndarray:
+    """The bytes of ``buf`` as a flat uint8 array, without a copy where the
+    buffer is contiguous (an array of any dtype reads as its bytes)."""
+    if isinstance(buf, np.ndarray):
+        return np.ascontiguousarray(buf).reshape(-1).view(np.uint8)
+    return np.frombuffer(buf, dtype=np.uint8)
+
+
+def byte_shuffle(buf, itemsize: int, out=None) -> bytearray:
+    """Byte planes of ``buf``: byte 0 of every item, then byte 1, and so on.
+
+    Each plane is one strided-read, contiguous-write copy into a single
+    output, so the work happens in numpy with the GIL released and scales
+    on a thread pool.  The output is ``out`` when given (any writable buffer
+    of ``buf``'s size), else a new ``bytearray``; its bytes equal
+    ``reshape(-1, itemsize).T.tobytes()``.
+    """
+    a = byte_view(buf)
     if a.size % itemsize:
         raise ValueError(f"buffer size {a.size} not divisible by itemsize {itemsize}")
-    return a.reshape(-1, itemsize).T.tobytes()
+    if out is None:
+        out = bytearray(a.size)
+    planes = np.frombuffer(out, np.uint8).reshape(itemsize, -1)
+    items = a.reshape(-1, itemsize)
+    for b in range(itemsize):
+        planes[b] = items[:, b]
+    return out
 
 
 def byte_unshuffle(buf: bytes | np.ndarray, itemsize: int) -> bytes:

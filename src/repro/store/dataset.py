@@ -77,7 +77,8 @@ class CZDataset:
         The dtype tag is re-derived per quantity from the appended array.
     workers:
         Encode threads shared by all member writes of this dataset
-        (``1`` = serial; output is byte-identical either way).
+        (``None``, the default, = one per CPU the process may run on;
+        ``1`` = serial; output is byte-identical either way).
     stats:
         Record per-member quality stats (PSNR / max error vs. the appended
         field, via :mod:`repro.core.metrics`) in each committed timestep —
@@ -86,7 +87,8 @@ class CZDataset:
     """
 
     def __init__(self, root, mode: str = "r",
-                 spec: CompressionSpec | None = None, workers: int = 1,
+                 spec: CompressionSpec | None = None,
+                 workers: int | None = None,
                  cache_readers: int = 8, cache_chunks: int = 8,
                  stats: bool = False, prefetch: int = 0):
         if mode not in ("r", "a"):

@@ -7,12 +7,14 @@ aggregation buffer and the buffers are concatenated in deterministic order.
 (scheme serialize + stage-2 lossless, both GIL-releasing) for *all*
 quantities of a timestep, while each CZ2 member file is drained by a single
 writer strictly in chunk order.  Serial (``workers=1``) and pooled output are
-byte-identical.
+byte-identical.  By default the pool has one thread per CPU the process may
+run on (:func:`host_threads`).
 """
 from __future__ import annotations
 
 import concurrent.futures
 import dataclasses
+import os
 import warnings
 
 import numpy as np
@@ -20,7 +22,15 @@ import numpy as np
 from repro.core import container
 from repro.core.pipeline import DTYPES, CompressionSpec, check_device
 
-__all__ = ["ShardWriter", "DtypeCoercionWarning"]
+__all__ = ["ShardWriter", "DtypeCoercionWarning", "host_threads"]
+
+
+def host_threads() -> int:
+    """Threads this process can run at once: the CPUs its affinity allows."""
+    try:
+        return len(os.sched_getaffinity(0))
+    except AttributeError:  # no CPU affinity on this platform
+        return os.cpu_count() or 1
 
 
 class DtypeCoercionWarning(UserWarning):
@@ -30,11 +40,13 @@ class DtypeCoercionWarning(UserWarning):
 
 
 class ShardWriter:
-    """Writes 3D fields to CZ2 member files through a shared encode pool."""
+    """Writes 3D fields to CZ2 member files through a shared encode pool of
+    ``workers`` threads (``None``: :func:`host_threads`; ``1``: serial)."""
 
-    def __init__(self, spec: CompressionSpec, workers: int = 1):
+    def __init__(self, spec: CompressionSpec, workers: int | None = None):
         self.spec = spec.validate()
-        self.workers = max(1, int(workers))
+        self.workers = (host_threads() if workers is None
+                        else max(1, int(workers)))
         self._pool = (concurrent.futures.ThreadPoolExecutor(self.workers)
                       if self.workers > 1 else None)
 

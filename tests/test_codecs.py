@@ -118,6 +118,95 @@ def test_byte_shuffle_roundtrip():
         assert shuf.bit_unshuffle(b, itemsize) == buf
 
 
+def _transposed(buf: bytes, itemsize: int) -> bytes:
+    """The byte shuffle as it was first written: one strided transpose."""
+    return np.frombuffer(buf, np.uint8).reshape(-1, itemsize).T.tobytes()
+
+
+_DTYPE_OF_SIZE = {1: np.uint8, 2: np.uint16, 4: np.uint32, 8: np.uint64}
+
+
+def _as_kind(raw: bytes, kind: str, itemsize: int):
+    if kind == "bytes":
+        return raw
+    if kind == "bytearray":
+        return bytearray(raw)
+    if kind == "uint8 array":
+        return np.frombuffer(raw, np.uint8).copy()
+    return np.frombuffer(raw, _DTYPE_OF_SIZE[itemsize]).copy()  # typed array
+
+
+@pytest.mark.parametrize("nitems", [0, 1, 1001, 4096])
+@pytest.mark.parametrize("kind", ["bytes", "bytearray", "uint8 array",
+                                  "typed array"])
+@pytest.mark.parametrize("itemsize", [1, 2, 4, 8])
+def test_byte_shuffle_matches_the_transpose(itemsize, kind, nitems):
+    """Byte planes written by column copies are the transpose's bytes, for
+    any buffer type and size (an array reads as its bytes), and unshuffle
+    back."""
+    raw = np.random.default_rng(nitems).integers(
+        0, 256, nitems * itemsize, dtype=np.uint8).tobytes()
+    s = shuf.byte_shuffle(_as_kind(raw, kind, itemsize), itemsize)
+    assert bytes(s) == _transposed(raw, itemsize)
+    assert shuf.byte_unshuffle(s, itemsize) == raw
+
+
+def test_byte_shuffle_writes_into_the_buffer_given():
+    raw = np.arange(4 * 1001, dtype=np.uint8).tobytes()
+    out = bytearray(3 + len(raw))
+    view = memoryview(out)[3:]
+    assert shuf.byte_shuffle(raw, 4, out=view) is view
+    assert bytes(out) == bytes(3) + _transposed(raw, 4)
+
+
+@pytest.mark.parametrize("kind", ["bytes", "bytearray", "uint8 array"])
+@pytest.mark.parametrize("itemsize", [2, 4, 8])
+def test_byte_shuffle_refuses_a_partial_item(itemsize, kind):
+    raw = bytes(4 * itemsize + 1)
+    with pytest.raises(ValueError, match="not divisible"):
+        shuf.byte_shuffle(_as_kind(raw, kind, itemsize), itemsize)
+
+
+def _serialized_by_concatenation(scheme, s1, lo, hi, spec) -> bytes:
+    """The chunk layouts as ``tobytes`` concatenations, the way the schemes
+    first wrote them."""
+    def shuffled(b):
+        if spec.shuffle == "none":
+            return b
+        return (_transposed(b, 4) if spec.shuffle == "byte"
+                else shuf.bit_shuffle(b, 4))
+
+    if scheme == "zfpx":
+        emax = np.clip(s1["emax"][lo:hi], -127, 127).astype(np.int8)
+        return emax.tobytes() + shuffled(
+            s1["q"][lo:hi].astype(np.int32).tobytes())
+    if scheme == "lorenzo":
+        return shuffled(s1["res"][lo:hi].astype(np.int32).tobytes())
+    mask, coeffs = s1["mask"][lo:hi], s1["coeffs"][lo:hi]
+    values = np.concatenate([s1["coarse"][lo:hi].astype(np.float32).reshape(-1),
+                             coeffs[mask].astype(np.float32)])
+    counts = mask.reshape(mask.shape[0], -1).sum(-1).astype(np.uint32)
+    return (counts.tobytes() + np.packbits(mask.reshape(-1)).tobytes()
+            + shuffled(values.tobytes()))
+
+
+@pytest.mark.parametrize("mode", ["byte", "none", "bit"])
+@pytest.mark.parametrize("scheme", ["zfpx", "lorenzo", "wavelet"])
+def test_payload_bytes_as_concatenated(scheme, mode):
+    """Each chunk payload, written once into one buffer, holds the bytes the
+    concatenating layout wrote."""
+    from repro.core import blocks as blk
+    from repro.core.schemes import get_scheme
+
+    spec = CompressionSpec(scheme=scheme, eps=1e-3, block_size=16,
+                           shuffle=mode)
+    sch = get_scheme(scheme)
+    s1 = sch.stage1(np.asarray(blk.blockify(FIELD, 16)), spec)
+    for lo, hi in ((0, 3), (3, 4), (4, 9)):
+        assert bytes(sch.serialize(s1, lo, hi, spec)) == \
+            _serialized_by_concatenation(scheme, s1, lo, hi, spec)
+
+
 def test_zero_low_bits():
     x = np.array([1.23456789, -9.87654e-3], np.float32)
     z = shuf.zero_low_bits_np(x, 8)

@@ -101,13 +101,18 @@ def test_append_rejects_bad_input(tmp_path):
 # Threaded vs serial writer determinism
 # ---------------------------------------------------------------------------
 
-def test_threaded_and_serial_writers_byte_identical(tmp_path):
-    spec = CompressionSpec(scheme="wavelet", block_size=BS,
+@pytest.mark.parametrize("workers", [4, None])
+@pytest.mark.parametrize("scheme", ["wavelet", "zfpx", "lorenzo"])
+def test_threaded_and_serial_writers_byte_identical(tmp_path, scheme, workers):
+    """A pool of 4 threads, and the default pool sized to the host, write
+    the serial writer's bytes."""
+    spec = CompressionSpec(scheme=scheme, block_size=BS,
                            buffer_bytes=1 << 14)
     members = {}
-    for workers in (1, 4):
-        root = os.path.join(tmp_path, f"w{workers}")
-        with CZDataset(root, "a", spec=spec, workers=workers) as ds:
+    for w in (1, workers):
+        root = os.path.join(tmp_path, f"w{w}")
+        kw = {} if w is None else {"workers": w}
+        with CZDataset(root, "a", spec=spec, **kw) as ds:
             for k in range(2):
                 ds.append(_stepped(k), time=float(k))
         for q in ("p", "rho"):
@@ -116,7 +121,39 @@ def test_threaded_and_serial_writers_byte_identical(tmp_path):
                 with open(os.path.join(root, rel), "rb") as f:
                     members.setdefault(rel, []).append(f.read())
     for rel, (serial, threaded) in members.items():
-        assert serial == threaded, f"{rel} differs between workers=1 and 4"
+        assert serial == threaded, \
+            f"{rel} differs between workers=1 and {workers}"
+
+
+def test_writer_pool_follows_the_host(monkeypatch):
+    """The default pool has one thread per CPU of the process's affinity;
+    with one CPU the writer is serial and makes no pool."""
+    monkeypatch.setattr(os, "sched_getaffinity", lambda pid: {0, 1, 2},
+                        raising=False)
+    with ShardWriter(SPEC) as w:
+        assert w.workers == 3 and w._pool is not None
+    monkeypatch.setattr(os, "sched_getaffinity", lambda pid: {0},
+                        raising=False)
+    with ShardWriter(SPEC) as w:
+        assert w.workers == 1 and w._pool is None
+    with ShardWriter(SPEC, workers=1) as w:
+        assert w._pool is None
+
+
+def test_rank_writer_stays_serial(tmp_path, monkeypatch):
+    """Rank processes share the host, so each keeps one encode thread
+    whatever the host has."""
+    from repro.cluster import RankWriter
+
+    monkeypatch.setattr(os, "sched_getaffinity", lambda pid: set(range(8)),
+                        raising=False)
+    root = os.path.join(tmp_path, "ds")
+    CZDataset(root, "a", spec=SPEC).close()
+    with RankWriter(root, 0) as rw:
+        assert rw._writer.workers == 1 and rw._writer._pool is None
+    snap = FieldSnapshotter(os.path.join(tmp_path, "snap"))
+    assert snap.ds._writer.workers == 1
+    snap.close()
 
 
 def test_pipeline_iter_chunks_parallel_byte_identical():
