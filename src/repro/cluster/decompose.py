@@ -93,8 +93,10 @@ def decompose(shape: tuple[int, int, int], ranks: int, block_size: int,
     """Split ``shape`` into ``ranks`` block-aligned subdomains.
 
     Subdomains are disjoint, cover the grid exactly, and are ordered by rank
-    in C order over the rank grid.  Raises if an axis has fewer block layers
-    than the layout wants parts (use a flatter layout or fewer ranks).
+    in C order over the rank grid.  Every cut is a multiple of the block
+    size; where an axis is not, its last part takes the partial blocks.
+    Raises if an axis has fewer block layers than the layout wants parts
+    (use a flatter layout or fewer ranks).
     """
     if layout not in LAYOUTS:
         raise ValueError(f"unknown layout {layout!r}; one of {LAYOUTS}")
@@ -112,7 +114,10 @@ def decompose(shape: tuple[int, int, int], ranks: int, block_size: int,
             raise ValueError(
                 f"layout {layout!r} cuts axis {ax} into {d} parts but it has "
                 f"only {n} blocks of side {block_size}")
-    cuts = [[b * block_size for b in _splits(n, d)] for n, d in zip(nb, dims)]
+    # cuts land on block boundaries; the last part along an axis ends at
+    # the field's edge and takes its partial blocks
+    cuts = [[min(b * block_size, s) for b in _splits(n, d)]
+            for n, d, s in zip(nb, dims, shape)]
     subs, rank = [], 0
     for i in range(dims[0]):
         for j in range(dims[1]):

@@ -189,18 +189,22 @@ def build_field_header(pipe: Pipeline, source,
     Header key *insertion order* decides byte identity of the JSON footer,
     so this is the one implementation shared by :func:`write_compressed` and
     the cluster engine's rank-parallel writer (``repro.cluster.engine``).
+    A field whose shape is not a multiple of the block size gets padded
+    edge blocks (:func:`repro.core.blocks.field_header` names the keys).
     """
     spec = pipe.spec
     data = np.asarray(source)
     header = pipe.base_header()
     if data.ndim == 3:
-        header["field_shape"] = list(data.shape)
+        header.update(blk.field_header(data.shape, spec.block_size,
+                                       spec.np_dtype.itemsize))
         with trace.span("blockify", bytes=int(data.nbytes)):
             data = np.asarray(
                 blk.blockify(np.asarray(data, spec.np_dtype), spec.block_size))
-    elif data.ndim != 4:
+    elif data.ndim == 4:
+        header["raw_bytes"] = int(data.size * spec.np_dtype.itemsize)
+    else:
         raise ValueError(f"expected 3D field or 4D block batch, got {data.shape}")
-    header["raw_bytes"] = int(data.size * spec.np_dtype.itemsize)
     if extra_header:
         header.update(extra_header)
     return header, data

@@ -47,7 +47,7 @@ from . import lossless, metrics
 from .schemes import DEVICES, Scheme, check_device, get_scheme
 from .schemes import SCHEMES  # noqa: F401  (re-export)
 
-__all__ = ["CODEC_FORMAT", "DTYPES", "DEVICES", "CompressionSpec",
+__all__ = ["BOUNDS", "CODEC_FORMAT", "DTYPES", "DEVICES", "CompressionSpec",
            "CompressedField", "Pipeline"]
 
 #: version of the per-chunk byte layout (v2: szx shuffles its outlier
@@ -57,6 +57,9 @@ CODEC_FORMAT = 3
 
 #: dtypes a container can record; CZ1/headerless payloads default to float32
 DTYPES = ("float32", "float64", "float16")
+
+#: what a spec's ``eps`` is relative to (see ``CompressionSpec.bound``)
+BOUNDS = ("abs", "range")
 
 # -- per-chunk accounting (the paper's per-stage timing, as live series) -----
 _ENC_CHUNKS = obs.counter("cz_pipeline_chunks_encoded_total",
@@ -78,6 +81,9 @@ _ENC_SECONDS = obs.histogram("cz_pipeline_encode_seconds",
                              "Per-chunk encode wall time by scheme.",
                              buckets=obs.FAST_BUCKETS,
                              labelnames=("scheme",))
+_STAGE1_ELEMENTS = obs.counter(
+    "cz_stage1_elements_total",
+    "Elements handed to scheme stage 1, edge-block padding included.")
 _DEC_SECONDS = obs.histogram("cz_pipeline_decode_seconds",
                              "Per-chunk decode wall time by scheme.",
                              buckets=obs.FAST_BUCKETS,
@@ -99,7 +105,7 @@ def _account_encode(scheme: str, raw: int, enc: int, seconds: float) -> None:
 class CompressionSpec:
     scheme: str = "wavelet"      # any name in repro.core.schemes.SCHEMES
     wavelet: str = "w3ai"        # w4i | w4l | w3ai
-    eps: float = 1e-3            # absolute error tolerance (wavelet/zfpx/szx)
+    eps: float = 1e-3            # error tolerance (wavelet/zfpx/szx/lorenzo)
     block_size: int = 32
     levels: int | None = None    # wavelet levels (None = max for block size)
     shuffle: str = "byte"        # none | byte | bit
@@ -110,6 +116,10 @@ class CompressionSpec:
     dtype: str = "float32"       # field dtype tag (see DTYPES)
     device: str = "host"         # stage-1 routing: host | jax (see DEVICES)
     extra: dict = dataclasses.field(default_factory=dict)  # third-party knobs
+    #: what ``eps`` is relative to: "abs" (an absolute error) or "range"
+    #: (a share of each field's value range, SZ's REL mode; resolved per
+    #: field by :meth:`resolve` into the "abs" spec a member records)
+    bound: str = "abs"
 
     def __hash__(self):
         # the generated hash would choke on the mutable `extra` dict; keep
@@ -124,6 +134,8 @@ class CompressionSpec:
             raise ValueError(f"unknown shuffle {self.shuffle}")
         if self.stage2 not in lossless.METHODS:
             raise ValueError(f"unknown stage2 {self.stage2}")
+        if self.bound not in BOUNDS:
+            raise ValueError(f"unknown bound {self.bound!r}; one of {BOUNDS}")
         if self.dtype not in DTYPES:
             raise ValueError(f"unknown dtype {self.dtype}; one of {DTYPES}")
         check_device(self.device)
@@ -136,7 +148,28 @@ class CompressionSpec:
         return np.dtype(self.dtype)
 
     def to_json(self) -> dict:
-        return dataclasses.asdict(self)
+        d = dataclasses.asdict(self)
+        if d["bound"] == "abs":
+            del d["bound"]  # headers written before "range" stay byte-identical
+        return d
+
+    def resolve(self, field) -> "CompressionSpec":
+        """The absolute spec one field is encoded with.  A "range" bound
+        becomes ``eps * (max - min)`` of the field; a constant field, whose
+        range is 0, is stored losslessly (scheme "raw").  An "abs" spec is
+        returned as it is."""
+        if self.bound == "abs":
+            return self
+        field = np.asarray(field)
+        lo, hi = float(np.min(field)), float(np.max(field))
+        if not np.isfinite(hi - lo):
+            raise ValueError(
+                f"a 'range' bound needs finite values, got [{lo}, {hi}]")
+        if hi == lo:
+            return dataclasses.replace(self, scheme="raw", eps=0.0,
+                                       bound="abs").validate()
+        return dataclasses.replace(self, eps=self.eps * (hi - lo),
+                                   bound="abs").validate()
 
     @staticmethod
     def from_json(d: dict) -> "CompressionSpec":
@@ -176,6 +209,10 @@ class Pipeline:
 
     def __init__(self, spec: CompressionSpec, workers: int = 1):
         self.spec = spec.validate()
+        if spec.bound != "abs":
+            raise ValueError(
+                f"a {spec.bound!r} bound depends on the field: resolve it "
+                "with CompressionSpec.resolve(field) first")
         self.scheme: Scheme = get_scheme(spec.scheme)
         self.workers = max(1, int(workers))
 
@@ -226,6 +263,7 @@ class Pipeline:
         """
         spec = self.spec
         blocks_np = np.asarray(blocks_np)
+        _STAGE1_ELEMENTS.inc(int(blocks_np.size))
         with trace.span("stage1", scheme=spec.scheme, device=spec.device,
                         nblocks=int(blocks_np.shape[0])):
             s1 = self.scheme.stage1(blocks_np, spec)
@@ -312,10 +350,10 @@ class Pipeline:
 
     def compress_field(self, field: np.ndarray,
                        extra_header: dict | None = None) -> CompressedField:
-        blocks_np = np.asarray(
-            blk.blockify(np.asarray(field, self.spec.np_dtype),
-                         self.spec.block_size))
-        hdr = {"field_shape": list(field.shape)}
+        field = np.asarray(field, self.spec.np_dtype)
+        blocks_np = np.asarray(blk.blockify(field, self.spec.block_size))
+        hdr = blk.field_header(field.shape, self.spec.block_size,
+                               field.itemsize)
         if extra_header:
             hdr.update(extra_header)
         return self.compress_blocks(blocks_np, hdr)

@@ -19,12 +19,12 @@ block is differenced independently, so blocks decompress in isolation.
 """
 from __future__ import annotations
 
-import functools
-
+import numpy as np
 import jax
 import jax.numpy as jnp
 
-__all__ = ["encode", "decode", "lorenzo_fwd", "lorenzo_inv", "max_eps_ratio"]
+__all__ = ["encode", "decode", "grid_scales", "lorenzo_fwd", "lorenzo_inv",
+           "max_eps_ratio"]
 
 # |q| must fit int32 with headroom for the 3D diff (factor <= 8).
 _Q_LIMIT = 2 ** 27
@@ -49,7 +49,15 @@ def lorenzo_inv(r):
     return r
 
 
-@functools.partial(jax.jit, static_argnames=("eps",))
+def grid_scales(eps: float) -> np.ndarray:
+    """``[1 / (2 eps), 2 eps]``, the quantization grid's constants, as the
+    float32 operand that encode and decode (and the Lorenzo kernels) take.
+    Worked out in double and rounded once, so every ``eps`` reaches the
+    device as the same float32 constants, and one compiled program serves
+    every ``eps``."""
+    return np.array([1.0 / (2.0 * eps), 2.0 * eps], np.float32)
+
+
 def encode(blocks, eps: float = 1e-3):
     """blocks (B, n, n, n) float32 -> int32 Lorenzo residuals (B, n, n, n).
 
@@ -58,18 +66,27 @@ def encode(blocks, eps: float = 1e-3):
     the first round we re-quantize the reconstruction residual.  This keeps
     |x - q*2eps| <= eps up to one ulp of x (tested with hypothesis).
     """
+    return _encode(blocks, grid_scales(eps))
+
+
+@jax.jit
+def _encode(blocks, scales):
     x = jnp.asarray(blocks, jnp.float32)
-    inv = 1.0 / (2.0 * eps)
+    inv, step = scales[0], scales[1]
     q = jnp.round(x * inv)
-    err = x - q * (2.0 * eps)
+    err = x - q * step
     q = (q + jnp.round(err * inv)).astype(jnp.int32)
     return lorenzo_fwd(q)
 
 
-@functools.partial(jax.jit, static_argnames=("eps",))
 def decode(residuals, eps: float = 1e-3):
+    return _decode(residuals, grid_scales(eps))
+
+
+@jax.jit
+def _decode(residuals, scales):
     q = lorenzo_inv(residuals)
-    return q.astype(jnp.float32) * (2.0 * eps)
+    return q.astype(jnp.float32) * scales[1]
 
 
 def check_eps(fields_absmax: float, eps: float) -> None:

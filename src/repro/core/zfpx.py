@@ -35,6 +35,7 @@ __all__ = [
     "pow2",
     "encode",
     "decode",
+    "plane_exponent",
     "fwd_lift_cell",
     "inv_lift_cell",
 ]
@@ -130,31 +131,50 @@ def _from_cells(cells, n):
     return c.reshape(b, n, n, n)
 
 
-def _drop_bits(emax, eps: float):
-    """Truncation shift per cell: deterministic in (emax, eps)."""
+def plane_exponent(eps: float) -> np.ndarray:
+    """``floor(log2 eps)``, all the truncation needs of ``eps``, as the
+    int32 operand that encode (and the zfpx kernel) take, so that one
+    compiled program serves every ``eps``."""
+    return np.array([int(np.floor(np.log2(eps))) if eps > 0 else -126],
+                    np.int32)
+
+
+def _drop_bits(emax, log_eps):
+    """Truncation shift per cell: deterministic in (emax, eps); ``log_eps``
+    is ``plane_exponent(eps)[0]``."""
     # grid unit is 2^(emax - SCALE_BITS); dropping p planes errs <= ~2^p units.
-    log_eps = int(np.floor(np.log2(eps))) if eps > 0 else -126
     p = log_eps - (emax - SCALE_BITS) - _GUARD_BITS
     return jnp.clip(p, 0, 31)
 
 
-@functools.partial(jax.jit, static_argnames=("eps",))
 def encode(blocks, eps: float = 1e-3):
     """blocks (B, n, n, n) float32 -> (emax (B, nc) int32, q (B, nc, 64) int32)."""
+    return _encode(blocks, plane_exponent(eps))
+
+
+@jax.jit
+def _encode(blocks, log_eps):
     cells = _to_cells(jnp.asarray(blocks, jnp.float32))     # (B, nc, 4,4,4)
     emax = cell_emax(jnp.max(jnp.abs(cells), axis=(-3, -2, -1)))  # (B, nc)
     scale = pow2(SCALE_BITS - emax)
     q = jnp.round(cells * scale[..., None, None, None]).astype(jnp.int32)
     q = fwd_lift_cell(q)
     q = q.reshape(*q.shape[:-3], 64)[..., jnp.asarray(sequency_perm())]
-    p = _drop_bits(emax, eps)[..., None]
+    p = _drop_bits(emax, log_eps[0])[..., None]
     q = jnp.where(emax[..., None] == _ZERO_EMAX, 0, (q >> p) << p)
     return emax, q
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "n"))
 def decode(emax, q, eps: float = 1e-3, n: int = 32):
-    """Inverse of :func:`encode` -> (B, n, n, n) float32."""
+    """Inverse of :func:`encode` -> (B, n, n, n) float32.  The truncation
+    is already in ``q``: ``eps`` is taken for a common signature and not
+    used, so it compiles nothing of its own."""
+    del eps
+    return _decode(emax, q, n=n)
+
+
+@functools.partial(jax.jit, static_argnames=("n",))
+def _decode(emax, q, n: int = 32):
     inv = jnp.argsort(jnp.asarray(sequency_perm()))
     cells = q[..., inv].reshape(*q.shape[:-1], 4, 4, 4)
     cells = inv_lift_cell(cells)

@@ -2,7 +2,9 @@
 
 Encode fuses compensated 2eps-grid quantization with the three axis-wise
 finite differences; decode fuses three inclusive prefix sums with
-dequantization.  Each grid step owns a tile of whole blocks in VMEM.
+dequantization.  Each grid step owns a tile of whole blocks in VMEM.  The
+grid's constants ``1/(2 eps)`` and ``2 eps`` are an operand in scalar
+memory, not compiled in, so one program serves every ``eps``.
 
 Layout: the wrapper views each (n, n, n) block as a lane-dense (n, n*n)
 slab — axis i on sublanes, (j, k) flattened onto lanes — a free reshape in
@@ -44,18 +46,18 @@ def _coords(shape, n: int):
     return rows, lanes // n, lanes % n
 
 
-def _enc_kernel(x_ref, o_ref, *, eps: float, n: int):
+def _enc_kernel(s_ref, x_ref, o_ref, *, n: int):
     x = x_ref[...]
-    inv = 1.0 / (2.0 * eps)
+    inv, step = s_ref[0], s_ref[1]
     q = jnp.round(x * inv)
-    q = (q + jnp.round((x - q * (2.0 * eps)) * inv)).astype(jnp.int32)
+    q = (q + jnp.round((x - q * step) * inv)).astype(jnp.int32)
     pos = _coords(q.shape, n)
     for axis in (0, 1, 2):
         q = q - _shifted(q, 1, axis, pos[axis], n)
     o_ref[...] = q
 
 
-def _dec_kernel(r_ref, o_ref, *, eps: float, n: int):
+def _dec_kernel(s_ref, r_ref, o_ref, *, n: int):
     r = r_ref[...]
     pos = _coords(r.shape, n)
     for axis in (2, 1, 0):
@@ -63,34 +65,35 @@ def _dec_kernel(r_ref, o_ref, *, eps: float, n: int):
         while s < n:
             r = r + _shifted(r, s, axis, pos[axis], n)
             s *= 2
-    o_ref[...] = r.astype(jnp.float32) * (2.0 * eps)
+    o_ref[...] = r.astype(jnp.float32) * s_ref[1]
 
 
-def _call(x, kern, name, out_dtype, eps, tile_blocks, interpret):
+def _call(x, kern, name, out_dtype, scales, tile_blocks, interpret):
     b, n = x.shape[0], x.shape[-1]
     tb = min(tile_blocks, b)
     if b % tb:
         tb = 1
     spec = pl.BlockSpec((tb, n, n * n), lambda i: (i, 0, 0))
     out = pl.pallas_call(
-        functools.partial(kern, eps=eps, n=n),
+        functools.partial(kern, n=n),
         grid=(b // tb,),
-        in_specs=[spec],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((b, n, n * n), out_dtype),
         interpret=interpret,
         name=name,
-    )(x.reshape(b, n, n * n))
+    )(jnp.asarray(scales, jnp.float32), x.reshape(b, n, n * n))
     return out.reshape(x.shape)
 
 
-def lorenzo_encode_pallas(blocks, eps: float = 1e-3,
+def lorenzo_encode_pallas(blocks, scales,
                           tile_blocks: int = DEFAULT_TILE_BLOCKS, interpret: bool = True):
+    """``scales`` is ``repro.core.szx.grid_scales(eps)``, in scalar memory."""
     return _call(jnp.asarray(blocks, jnp.float32), _enc_kernel,
-                 "lorenzo_encode", jnp.int32, eps, tile_blocks, interpret)
+                 "lorenzo_encode", jnp.int32, scales, tile_blocks, interpret)
 
 
-def lorenzo_decode_pallas(residuals, eps: float = 1e-3,
+def lorenzo_decode_pallas(residuals, scales,
                           tile_blocks: int = DEFAULT_TILE_BLOCKS, interpret: bool = True):
     return _call(jnp.asarray(residuals, jnp.int32), _dec_kernel,
-                 "lorenzo_decode", jnp.float32, eps, tile_blocks, interpret)
+                 "lorenzo_decode", jnp.float32, scales, tile_blocks, interpret)

@@ -8,7 +8,11 @@ elsewhere must not run the kernels interpreted without saying so.
 
 Every wrapper counts its calls (``cz_kernel_calls_total``) and its first
 call per argument signature (shapes/dtypes + static values — the same key
-``jax.jit`` compiles on) as a compile (``cz_kernel_compiles_total``).  The
+``jax.jit`` compiles on) as a compile (``cz_kernel_compiles_total``).
+The zfpx and Lorenzo wrappers take ``eps`` as a float, as the host
+references in ``repro.core`` do, and hand the kernel what it needs of it
+as an array operand (``szx.grid_scales``, ``zfpx.plane_exponent``): a
+field of another ``eps`` runs the same compiled program.  The
 wrappers time nothing and never block: they return the unforced array, so
 JAX's async dispatch pipelines on accelerator backends whether or not
 anyone is tracing.  A kernel's device time is the profiler trace's (the
@@ -22,6 +26,8 @@ import threading
 import jax
 
 from repro import obs
+from repro.core.szx import grid_scales
+from repro.core.zfpx import plane_exponent
 
 from .lorenzo import lorenzo_decode_pallas, lorenzo_encode_pallas
 from .wavelet3d import wavelet3d_forward, wavelet3d_inverse
@@ -53,8 +59,12 @@ def _sig(x):
     return ("val", x)
 
 
-def _instrument(name: str):
+def _instrument(name: str, eps_as: tuple | None = None):
     """Wrap one jitted kernel with the ``cz_kernel_*`` counters.
+
+    ``eps_as = (arg, to_operand)`` has the wrapper take ``eps`` (a float)
+    and pass the jit ``arg=to_operand(eps)`` in its place, before the key
+    is taken: ``eps`` is then a value, never part of the compile key.
 
     The first call for a signature counts as a compile, through a
     per-wrapper seen-set that mirrors ``jax.jit``'s cache key (argument
@@ -69,6 +79,9 @@ def _instrument(name: str):
 
         @functools.wraps(fn)
         def wrapper(*a, **k):
+            if eps_as is not None:
+                arg, to_operand = eps_as
+                k[arg] = to_operand(k.pop("eps", 1e-3))
             key = (tuple(_sig(x) for x in a),
                    tuple(sorted((kk, _sig(v)) for kk, v in k.items())))
             with lock:
@@ -112,26 +125,27 @@ def wavelet_inverse(blocks, kind: str = "w3ai", levels: int | None = None,
     return wavelet3d_inverse(blocks, kind, levels, interpret=_interp(interpret))
 
 
-@_instrument("zfpx_encode")
-@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
-def zfpx_encode(blocks, eps: float = 1e-3, interpret: bool | None = None):
-    return zfpx_encode_pallas(blocks, eps, interpret=_interp(interpret))
+@_instrument("zfpx_encode", eps_as=("log_eps", plane_exponent))
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def zfpx_encode(blocks, log_eps, interpret: bool | None = None):
+    return zfpx_encode_pallas(blocks, log_eps, interpret=_interp(interpret))
 
 
-@_instrument("zfpx_decode")
-@functools.partial(jax.jit, static_argnames=("eps", "n", "interpret"))
-def zfpx_decode(emax, q, eps: float = 1e-3, n: int = 32,
+@_instrument("zfpx_decode", eps_as=("log_eps", plane_exponent))
+@functools.partial(jax.jit, static_argnames=("n", "interpret"))
+def zfpx_decode(emax, q, log_eps=None, n: int = 32,
                 interpret: bool | None = None):
-    return zfpx_decode_pallas(emax, q, eps, n, interpret=_interp(interpret))
+    del log_eps  # the truncation is already in q
+    return zfpx_decode_pallas(emax, q, n, interpret=_interp(interpret))
 
 
-@_instrument("lorenzo_encode")
-@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
-def lorenzo_encode(blocks, eps: float = 1e-3, interpret: bool | None = None):
-    return lorenzo_encode_pallas(blocks, eps, interpret=_interp(interpret))
+@_instrument("lorenzo_encode", eps_as=("scales", grid_scales))
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def lorenzo_encode(blocks, scales, interpret: bool | None = None):
+    return lorenzo_encode_pallas(blocks, scales, interpret=_interp(interpret))
 
 
-@_instrument("lorenzo_decode")
-@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
-def lorenzo_decode(residuals, eps: float = 1e-3, interpret: bool | None = None):
-    return lorenzo_decode_pallas(residuals, eps, interpret=_interp(interpret))
+@_instrument("lorenzo_decode", eps_as=("scales", grid_scales))
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def lorenzo_decode(residuals, scales, interpret: bool | None = None):
+    return lorenzo_decode_pallas(residuals, scales, interpret=_interp(interpret))

@@ -3,7 +3,8 @@
 Fuses the zfpx substage-1 pipeline for a VMEM-resident tile of blocks:
 exponent extraction, fixed-point conversion, the ZFP integer lifting
 transform along three axes, total-sequency reorder, and the eps-derived
-bit-plane truncation.  Everything is elementwise int32 work on whole vregs
+bit-plane truncation (its ``floor(log2 eps)`` an operand in scalar memory,
+so one program serves every ``eps``).  Everything is elementwise int32 work on whole vregs
 — pure VPU, no divergent control flow (zero cells are handled by masking).
 
 Layout: the wrapper gathers the 4x4x4 cells (an XLA transpose in HBM) into
@@ -19,11 +20,10 @@ The decode kernel inverts: inverse reorder, inverse lifting, dequantize.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import zfpx as _z
 
@@ -39,13 +39,13 @@ def _lift_axis(c, axis: int, fn):
     return jnp.stack(fn(*parts), axis=axis)
 
 
-def _encode_kernel(x_ref, emax_ref, q_ref, *, eps: float):
+def _encode_kernel(e_ref, x_ref, emax_ref, q_ref):
     cells = x_ref[...]                               # (4,4,4, tb, nc) f32
     emax = _z.cell_emax(jnp.max(jnp.abs(cells), axis=(0, 1, 2)))
     q = jnp.round(cells * _z.pow2(_z.SCALE_BITS - emax)).astype(jnp.int32)
     for axis in (0, 1, 2):
         q = _lift_axis(q, axis, _z._lift4)
-    p = _z._drop_bits(emax, eps)
+    p = _z._drop_bits(emax, e_ref[0])
     zero = emax == _z._ZERO_EMAX
     for r, idx in enumerate(_z.sequency_perm().tolist()):
         c = q[idx // 16, (idx // 4) % 4, idx % 4]
@@ -97,16 +97,19 @@ def _from_slabs(slabs, n: int):
     return jnp.transpose(c, (3, 4, 0, 5, 1, 6, 2)).reshape(b, n, n, n)
 
 
-def zfpx_encode_pallas(blocks, eps: float = 1e-3,
+def zfpx_encode_pallas(blocks, log_eps,
                        tile_blocks: int = DEFAULT_TILE_BLOCKS, interpret: bool = True):
+    """``log_eps`` is ``repro.core.zfpx.plane_exponent(eps)``, in scalar
+    memory, so one program serves every ``eps``."""
     b, n = blocks.shape[0], blocks.shape[-1]
     nc = (n // 4) ** 3
     tb, bp = _tile(b, tile_blocks)
     x = _pad(_to_slabs(jnp.asarray(blocks, jnp.float32)), 3, bp)
     emax, q = pl.pallas_call(
-        functools.partial(_encode_kernel, eps=eps),
+        _encode_kernel,
         grid=(bp // tb,),
-        in_specs=[pl.BlockSpec((4, 4, 4, tb, nc), lambda i: (0, 0, 0, i, 0))],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec((4, 4, 4, tb, nc), lambda i: (0, 0, 0, i, 0))],
         out_specs=[
             pl.BlockSpec((tb, nc), lambda i: (i, 0)),
             pl.BlockSpec((64, tb, nc), lambda i: (0, i, 0)),
@@ -117,13 +120,14 @@ def zfpx_encode_pallas(blocks, eps: float = 1e-3,
         ],
         interpret=interpret,
         name="zfpx_encode",
-    )(x)
+    )(jnp.asarray(log_eps, jnp.int32), x)
     return emax[:b], jnp.transpose(q[:, :b], (1, 2, 0))
 
 
-def zfpx_decode_pallas(emax, q, eps: float = 1e-3, n: int = 32,
+def zfpx_decode_pallas(emax, q, n: int = 32,
                        tile_blocks: int = DEFAULT_TILE_BLOCKS, interpret: bool = True):
-    del eps  # the truncation is already applied to q; decode needs emax only
+    """The truncation is already applied to ``q``: decode needs ``emax``
+    only."""
     b, nc = emax.shape
     tb, bp = _tile(b, tile_blocks)
     e = _pad(jnp.asarray(emax, jnp.int32), 0, bp)

@@ -51,7 +51,10 @@ class ShardWriter:
                       if self.workers > 1 else None)
 
     def spec_for(self, field: np.ndarray) -> CompressionSpec:
-        """Dataset spec re-tagged with the field's dtype (auto dtype tags).
+        """The spec one member is written with: the dataset spec re-tagged
+        with the field's dtype (auto dtype tags), and with a "range" bound
+        resolved to the field's own absolute ``eps``
+        (:meth:`CompressionSpec.resolve`, SZ's REL mode per member).
         Dtypes the spec's scheme can't take (unsupported ones, or e.g.
         float64 into an fpzipx dataset) fall back to the spec's own dtype —
         the field is coerced, never rejected mid-append, but the cast is
@@ -61,6 +64,9 @@ class ShardWriter:
         host path under a lying header, so it raises here even if the spec
         skipped validation (e.g. was rebuilt from a hand-edited manifest)."""
         check_device(self.spec.device)
+        return self._dtype_spec(field).resolve(field)
+
+    def _dtype_spec(self, field: np.ndarray) -> CompressionSpec:
         dt = str(np.asarray(field).dtype)
         if dt == self.spec.dtype:
             return self.spec
@@ -68,7 +74,7 @@ class ShardWriter:
             warnings.warn(
                 f"dtype {dt} is not a supported field dtype {DTYPES}; "
                 f"values will be cast to {self.spec.dtype}",
-                DtypeCoercionWarning, stacklevel=3)
+                DtypeCoercionWarning, stacklevel=4)
             return self.spec
         try:
             return dataclasses.replace(self.spec, dtype=dt).validate()
@@ -76,7 +82,7 @@ class ShardWriter:
             warnings.warn(
                 f"scheme {self.spec.scheme!r} cannot encode dtype {dt} "
                 f"({e}); values will be cast to {self.spec.dtype}",
-                DtypeCoercionWarning, stacklevel=3)
+                DtypeCoercionWarning, stacklevel=4)
             return self.spec
 
     def write(self, path: str, field: np.ndarray,

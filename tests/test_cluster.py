@@ -103,6 +103,31 @@ def test_rank_invariance_auto_mixed_schemes(engine, tmp_path):
     assert np.max(np.abs(field - dec)) <= 1e-4 * rngv * (1 + 1e-6)
 
 
+@pytest.mark.parametrize("scheme", ["lorenzo", "wavelet", "raw"])
+def test_rank_invariance_non_multiple_shape(engine, scheme, tmp_path):
+    """A (40, 72, 100) field at block 16 has partial blocks along every
+    axis; the shared file is byte-identical to the serial writer at ranks
+    1, 2 and 4, and reads back at the field's own shape."""
+    field = np.random.default_rng(5).standard_normal(
+        (40, 72, 100)).astype(np.float32)
+    spec = _spec(scheme)
+    serial = os.path.join(tmp_path, "serial.cz")
+    n_serial = container.write_field(serial, field, spec)
+    with open(serial, "rb") as f:
+        ref = f.read()
+    for ranks in (1, 2, 4):
+        path = os.path.join(tmp_path, f"r{ranks}.cz")
+        assert engine.compress(path, field, spec, ranks=ranks) == n_serial
+        with open(path, "rb") as f:
+            assert f.read() == ref, f"{scheme} ranks={ranks}"
+    with container.FieldReader(os.path.join(tmp_path, "r4.cz")) as r:
+        assert r.header["pad"] == "edge"
+        assert r.header["raw_bytes"] == field.nbytes
+        assert r.read_all().shape == field.shape
+    plan = engine.plan(field.shape, spec, ranks=4)
+    assert sum(p["nblocks"] for p in plan) == 3 * 5 * 7
+
+
 def test_engine_more_ranks_than_chunks(engine, tmp_path):
     """Ranks beyond the chunk count contribute zero bytes, not corruption."""
     spec = CompressionSpec(scheme="raw", block_size=BS, buffer_bytes=1 << 22)

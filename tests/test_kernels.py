@@ -4,6 +4,7 @@ tests skip cleanly on a bare interpreter (no hypothesis); any environment
 installed via ``pip install -e ".[test]"`` — both CI jobs included — has
 hypothesis and runs them for real."""
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -196,7 +197,7 @@ def test_kernel_metrics_split_compile_from_execute():
     calls = obs.REGISTRY.get("cz_kernel_calls_total")
     assert "cz_kernel_seconds" not in {m.name for m in obs.REGISTRY}
 
-    x = blocks(2, 8, seed=991)  # fresh shape: unseen by earlier tests
+    x = blocks(9, 8, seed=991)  # fresh shape: unseen by earlier tests
     c0, n0 = compiles.value(**lbl), calls.value(**lbl)
     ops.lorenzo_encode(x, eps=2e-3, interpret=True)
     assert compiles.value(**lbl) == c0 + 1
@@ -205,10 +206,55 @@ def test_kernel_metrics_split_compile_from_execute():
         ops.lorenzo_encode(x, eps=2e-3, interpret=True)
     assert compiles.value(**lbl) == c0 + 1
     assert calls.value(**lbl) == n0 + 3
-    # a new eps is a new static value -> new jit cache entry -> compile
+    # a new eps is a new operand value, not a new program: no compile
     ops.lorenzo_encode(x, eps=3e-3, interpret=True)
-    assert compiles.value(**lbl) == c0 + 2
+    assert compiles.value(**lbl) == c0 + 1
     assert calls.value(**lbl) == n0 + 4
+
+
+def _eps_calls():
+    """Each kernel wrapper and host reference that takes ``eps``, as a call
+    on fresh blocks of an eps, and the jitted function it runs."""
+    from repro.core import szx, zfpx
+
+    x = blocks(6, 8, seed=517)
+    emax, q = zfpx.encode(x, eps=1e-3)
+    res = szx.encode(x, eps=1e-3)
+    k = dict(interpret=True)
+    return {
+        "zfpx_encode": (lambda e: ops.zfpx_encode(x, eps=e, **k),
+                        ops.zfpx_encode.__wrapped__),
+        "zfpx_decode": (lambda e: ops.zfpx_decode(emax, q, eps=e, n=8, **k),
+                        ops.zfpx_decode.__wrapped__),
+        "lorenzo_encode": (lambda e: ops.lorenzo_encode(x, eps=e, **k),
+                           ops.lorenzo_encode.__wrapped__),
+        "lorenzo_decode": (lambda e: ops.lorenzo_decode(res, eps=e, **k),
+                           ops.lorenzo_decode.__wrapped__),
+        "zfpx_host_encode": (lambda e: zfpx.encode(x, eps=e), zfpx._encode),
+        "zfpx_host_decode": (lambda e: zfpx.decode(emax, q, eps=e, n=8),
+                             zfpx._decode),
+        "szx_host_encode": (lambda e: szx.encode(x, eps=e), szx._encode),
+        "szx_host_decode": (lambda e: szx.decode(res, eps=e), szx._decode),
+    }
+
+
+@pytest.mark.parametrize("name", [
+    "zfpx_encode", "zfpx_decode", "lorenzo_encode", "lorenzo_decode",
+    "zfpx_host_encode", "zfpx_host_decode", "szx_host_encode",
+    "szx_host_decode"])
+def test_one_program_serves_every_eps(name):
+    """``eps`` reaches the jit as an operand: fields of other eps (as a
+    value-range-relative bound gives every member) reuse the program
+    compiled for the first, and still get their own results."""
+    call, jitted = _eps_calls()[name]
+    call(1e-3)
+    size = jitted._cache_size()
+    outs = [np.asarray(jax.tree_util.tree_leaves(call(eps))[-1])
+            for eps in (1e-3, 2.7e-3, 4.1e-2, 7.3e-5)]
+    assert jitted._cache_size() == size
+    # zfpx decodes from q alone; every other result follows its eps
+    assert np.array_equal(outs[0], outs[2]) == ("zfpx" in name
+                                                and "decode" in name)
 
 
 def test_kernel_wrapper_returns_unforced_while_tracing(monkeypatch):

@@ -45,11 +45,12 @@ def _args(kernel: str, b: int, sharding):
     return {
         "wavelet_forward": ((blocks,), {"kind": "w3ai"}),
         "wavelet_inverse": ((blocks,), {"kind": "w3ai"}),
-        "zfpx_encode": ((blocks,), {"eps": 1e-3}),
+        "zfpx_encode": ((blocks,), {"log_eps": arr((1,), jnp.int32)}),
         "zfpx_decode": ((arr((b, NC), jnp.int32), arr((b, NC, 64), jnp.int32)),
-                        {"eps": 1e-3, "n": N}),
-        "lorenzo_encode": ((blocks,), {"eps": 1e-3}),
-        "lorenzo_decode": ((arr((b, N, N, N), jnp.int32),), {"eps": 1e-3}),
+                        {"log_eps": arr((1,), jnp.int32), "n": N}),
+        "lorenzo_encode": ((blocks,), {"scales": arr((2,), jnp.float32)}),
+        "lorenzo_decode": ((arr((b, N, N, N), jnp.int32),),
+                           {"scales": arr((2,), jnp.float32)}),
     }[kernel]
 
 
